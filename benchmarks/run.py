@@ -362,11 +362,13 @@ print("SHARDED_OK")
 
 def _sharded_parity_check() -> int:
     """Forced 2-CPU-device subprocess: the sharded runner must produce
-    bitwise-identical ResultSet metrics to the single-device run."""
+    bitwise-identical ResultSet metrics to the single-device run. The
+    child runs on the CPU backend only: on a TPU host this process
+    already holds the chip, which a second process cannot open."""
     import subprocess
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT],

@@ -4,17 +4,18 @@
 sub-jaxprs (pjit bodies, while cond/body, scan bodies, cond branches,
 custom_* rules) with a structural path, so analyzers can tell whether
 an op sits inside a loop body. `loops` yields each `while`/`scan`
-equation together with its carried output avals — for `while` the body
+equation together with its carried avals — for `while` the body
 jaxpr's outputs *are* the carry; for `scan` the first ``num_carry``
-outputs are.
+outputs are, plus the enclosing loop's carries that it takes as
+consts.
 """
 from __future__ import annotations
 
-from typing import Any, Iterator, List, Tuple
+from typing import Any, FrozenSet, Iterator, List, Tuple
 
 
 def _sub_jaxprs(params: dict) -> Iterator[Any]:
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for vv in vs:
@@ -39,14 +40,41 @@ def in_loop(path: Tuple[str, ...]) -> bool:
     return "while" in path or "scan" in path
 
 
-def loops(jaxpr) -> Iterator[Tuple[Tuple[str, ...], Any, List[Any]]]:
-    """Yield ``(path, eqn, carry_avals)`` for every while/scan."""
-    for path, eqn in walk_eqns(jaxpr):
+def loops(jaxpr, path: Tuple[str, ...] = (),
+          carried: FrozenSet[int] = frozenset()
+          ) -> Iterator[Tuple[Tuple[str, ...], Any, List[Any]]]:
+    """Yield ``(path, eqn, carry_avals)`` for every while/scan.
+
+    ``carried`` holds the ids of the variables that are loop state of
+    an enclosing loop. A scan (what ``fori_loop`` lowers to) passes a
+    carry that its body returns unchanged in as a const operand, so
+    such a const is still state the inner loop holds: it is counted
+    with the scan's carries."""
+    for eqn in jaxpr.eqns:
         name = eqn.primitive.name
+        subs = list(_sub_jaxprs(eqn.params))
         if name == "while":
             body = eqn.params["body_jaxpr"].jaxpr
             yield path, eqn, [v.aval for v in body.outvars]
+            state = body.invars[eqn.params["body_nconsts"]:]
+            subs = [(eqn.params["cond_jaxpr"].jaxpr, frozenset()),
+                    (body, frozenset(map(id, state)))]
         elif name == "scan":
             body = eqn.params["jaxpr"].jaxpr
-            nc = eqn.params["num_carry"]
-            yield path, eqn, [v.aval for v in body.outvars[:nc]]
+            nk, nc = eqn.params["num_consts"], eqn.params["num_carry"]
+            fwd = [i for i, v in enumerate(eqn.invars[:nk])
+                   if id(v) in carried]
+            yield path, eqn, ([v.aval for v in body.outvars[:nc]]
+                              + [eqn.invars[i].aval for i in fwd])
+            state = ([body.invars[i] for i in fwd]
+                     + body.invars[nk:nk + nc])
+            subs = [(body, frozenset(map(id, state)))]
+        else:
+            # pjit / closed_call / cond branches take the trailing
+            # operands positionally
+            subs = [(sub, frozenset(
+                id(iv) for iv, ov in zip(sub.invars[::-1],
+                                         eqn.invars[::-1])
+                if id(ov) in carried)) for sub in subs]
+        for sub, state in subs:
+            yield from loops(sub, path + (name,), state)

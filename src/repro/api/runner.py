@@ -216,18 +216,19 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
             tl_bucket=spec.tl_bucket,
             keep_responses=spec.keep_per_request,
             trace=spec.trace_events)
-        return ci, jax.device_get(out)
+        placed = sorted({f"{d.platform}:{d.id}" for v in out.values()
+                         for d in v.devices()})
+        return ci, jax.device_get(out), placed
 
     if spec.trace_events:
         # one collect scope per chunk: device_get inside run_chunk
         # blocks, so every ordered flush lands before the scope closes
         from repro.telemetry import rail
-        outs = {}
+        outs, placed = {}, {}
         lane_events: Dict[tuple, dict] = {}
         for ci in mine:
             with rail.collect() as sink:
-                _, out = run_chunk(ci)
-            outs[ci] = out
+                _, outs[ci], placed[ci] = run_chunk(ci)
             pi, lo, hi = plan[ci]
             for j in range(hi - lo):
                 lane_events[(pi, lo + j)] = sink.lane_events(j)
@@ -238,7 +239,9 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
         # chunk k
         workers = max(2, len(devs))
         with ThreadPoolExecutor(max_workers=workers) as tp:
-            outs = dict(tp.map(run_chunk, mine))
+            done = list(tp.map(run_chunk, mine))
+        outs = {ci: out for ci, out, _ in done}
+        placed = {ci: ids for ci, _, ids in done}
 
     # ------------------------------------------------------- assembly
     P = len(spec.policies)
@@ -283,6 +286,7 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
                             if isinstance(spec.deadlines, float)
                             else list(spec.deadlines))),
                 n_devices=len(devs), backend=jax.default_backend(),
+                chunk_devices=[placed[ci] for ci in mine],
                 resilience=spec.resilience_meta(),
                 seeds=(list(spec.seeds) if spec.seeds is not None
                        else None),

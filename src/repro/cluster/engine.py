@@ -109,7 +109,8 @@ from repro.core.jax_engine import (BIG, BUSY, CI_DONE, CI_EXH,
                                    CI_FAILED, CI_ITERS, CI_NEXT,
                                    CI_OVF, CI_RETRY, CI_SHED, CI_STALL,
                                    CI_TERM, CI_TMO, CI_TRIPS, COLD,
-                                   HIST_BINS, I32_MAX, IDLE, NCF, NCI,
+                                   HIST_BINS, I32_MAX, IDLE,
+                                   LOOP_COMPILER_OPTIONS, NCF, NCI,
                                    SEG, EngineCtx, _fold_event, _gidx,
                                    ensure_x64, hist_quantile)
 from repro.core.resilience import backoff_jax
@@ -1625,7 +1626,8 @@ def _simulate_cluster(fn_id, arrival, exec_time, t_cold, t_evict,
                                     "has_delay", "has_churn",
                                     "var_delay", "seg",
                                     "keep_responses", "resil",
-                                    "trace"))
+                                    "trace"),
+                   compiler_options=LOOP_COMPILER_OPTIONS)
 def _cluster_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                      threshold, delays=None, churn_t=None, dtimes=None,
                      dvals=None, dper=None, deadlines=None,
@@ -1707,7 +1709,8 @@ def _cluster_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                cold_time=out["cold_time"],
                evictions=out["evictions"],
                overflow=out["overflow"],
-               stalled=out["stalled"])
+               stalled=out["stalled"],
+               n_events=out["n_events"])
     if tl_bins:
         res["tl_count"] = out["tl_count"]
         res["tl_resp_sum"] = out["tl_resp_sum"]

@@ -127,8 +127,8 @@ def build_node_streams(arrays: Dict[str, np.ndarray],
 # metrics sum in any order; max is order-free
 _SUM_F = ("resp_sum", "slow_sum", "cold_time", "evict_time")
 _SUM_I = ("cold_starts", "evictions", "overflow", "stalled", "done",
-          "resp_hist", "deadline_miss", "failed", "timed_out",
-          "retried", "shed", "failed_exhausted")
+          "n_events", "resp_hist", "deadline_miss", "failed",
+          "timed_out", "retried", "shed", "failed_exhausted")
 _SUM_F_TL = ("tl_resp_sum", "tl_exec_sum")
 _SUM_I_TL = ("tl_count",)
 

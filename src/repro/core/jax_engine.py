@@ -138,10 +138,11 @@ XLA CPU backend:
 
 1. *No control flow inside the body.* Every handler runs every
    iteration gated by an ``on`` predicate, and all writes are guarded
-   scatters — ``mode="drop"`` with an out-of-bounds sentinel index when
-   disabled (`_gidx`). A ``lax.cond`` under vmap lowers to a `select`
-   over every carried array, i.e. a dense copy of the whole state per
-   event.
+   by an out-of-bounds sentinel index when disabled (`_gidx`): one-hot
+   selects on the small carried arrays (`_put` / `_bump`), scatters
+   with ``mode="drop"`` on the per-request rails. A ``lax.cond`` under
+   vmap lowers to a `select` over every carried array, i.e. a dense
+   copy of the whole state per event.
 2. *Lanes live inside the loop.* One ``while_loop`` carries (L, ...)
    state and the branchless body is vmapped per lane; finished lanes
    no-op through their guards. Vmapping the ``while_loop`` itself would
@@ -197,29 +198,36 @@ import os
 import time
 from typing import Dict, Optional, Sequence, Union
 
-# The engine's event loop is hundreds of tiny fused ops per simulated
-# event; XLA:CPU's thunk runtime pays a dispatch overhead per op that
-# slows the loop ~10x vs the legacy single-LLVM-function emitter. Ask
-# for the legacy runtime before JAX initialises its CPU client (no-op
-# for other backends, and respected only if the backend isn't live yet;
-# callers can override by setting the flag themselves).
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_cpu_use_thunk_runtime" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_cpu_use_thunk_runtime=false").strip()
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
-import jax                      # noqa: E402
-import jax.numpy as jnp         # noqa: E402
-import numpy as np              # noqa: E402
-from jax import lax             # noqa: E402
-
-from repro.core.request import Trace  # noqa: E402
-from repro.core.resilience import backoff_jax  # noqa: E402
+from repro.core.request import Trace
+from repro.core.resilience import backoff_jax
 
 BIG = 1e30
 COLD, IDLE, BUSY = 0, 1, 2
 I32_MAX = np.iinfo(np.int32).max
 SEG = 32          # events per segment (deferred result-write window)
+
+# Compiler options of every jitted event loop (here and in
+# `repro.cluster.engine`). The TPU compiler's loop-range analysis
+# (`tpu-indexed-instruction-analyzer`) extracts each while-loop body,
+# simplifies the copy and kills the process on the event loops:
+#   algebraic_simplifier.cc:580] Check failed: computation->Accept(this)
+#   is OK (FAILED_PRECONDITION: A cycle is detected while visiting
+#   instruction ... control-predecessors={...}
+# The control predecessors are the ordering edges that copy insertion
+# puts around an in-place scatter on a carried array that the same body
+# also reads: the resilience link rails (`nxt`, `att`, `rt_t`) and the
+# cluster's queue and link rails. The small per-lane arrays avoid them
+# by spelling (`_put` / `_bump`); the per-request rails cannot, and
+# which of them trips the check moves with unrelated edits to the body.
+# Other backends have no pass of that name, so their programs do not
+# change.
+LOOP_COMPILER_OPTIONS = {
+    "xla_disable_hlo_passes": "tpu-indexed-instruction-analyzer"}
 
 # Requests per trace window: the four slabs cost 24 bytes/request
 # (2 x f64 + 2 x i32), so 524288 bounds the gather working set to
@@ -281,9 +289,15 @@ ensure_x64()
 
 # ---------------------------------------------------------- lane batching
 def default_lane_chunk(backend: Optional[str] = None) -> int:
-    """Table entry for the active (or given) JAX backend."""
-    return LANE_CHUNKS.get(backend or jax.default_backend(),
-                           LANE_CHUNKS["cpu"])
+    """Table entry for the active (or given) JAX backend. A backend
+    the table does not name raises: its entry has to be measured, not
+    borrowed from the CPU's."""
+    backend = backend or jax.default_backend()
+    if backend not in LANE_CHUNKS:
+        raise ValueError(
+            f"no LANE_CHUNKS entry for JAX backend {backend!r} "
+            f"(known: {sorted(LANE_CHUNKS)})")
+    return LANE_CHUNKS[backend]
 
 
 def resolve_lane_chunk(setting: Union[int, str, None] = None) -> int:
@@ -523,12 +537,10 @@ class EngineCtx:
         full = s["q_len"][fc] >= self.Q
         do = on & ~full
         s = dict(s)
-        s["q_head_rid"] = s["q_head_rid"].at[
-            _gidx(do & was_empty, fn, self.F)].set(
-            jnp.asarray(rid, jnp.int32), mode="drop")
-        s["q_len"] = s["q_len"].at[_gidx(do, fn, self.F)].add(
-            1, mode="drop")
-        s["ci"] = s["ci"].at[CI_OVF].add((on & full).astype(jnp.int32))
+        s["q_head_rid"] = _put(s["q_head_rid"],
+                               _gidx(do & was_empty, fn, self.F), rid)
+        s["q_len"] = _bump(s["q_len"], _gidx(do, fn, self.F), 1)
+        s["ci"] = _bump(s["ci"], CI_OVF, on & full)
         return s, do
 
     def q_consume_direct(self, s, fn, on):
@@ -537,8 +549,8 @@ class EngineCtx:
         cache stays stale-but-gated (q_len == 0) until the next push
         rewrites it."""
         s = dict(s)
-        s["q_head_pos"] = s["q_head_pos"].at[
-            _gidx(on, fn, self.F)].add(1, mode="drop")
+        s["q_head_pos"] = _bump(s["q_head_pos"], _gidx(on, fn, self.F),
+                                1)
         return s
 
     def q_pop(self, s, fn, on):
@@ -551,9 +563,9 @@ class EngineCtx:
         succ = self.rid_at_pos(fc, s["q_head_pos"][fc] + 1)
         fi = _gidx(on, fn, self.F)
         s = dict(s)
-        s["q_head_rid"] = s["q_head_rid"].at[fi].set(succ, mode="drop")
-        s["q_head_pos"] = s["q_head_pos"].at[fi].add(1, mode="drop")
-        s["q_len"] = s["q_len"].at[fi].add(-1, mode="drop")
+        s["q_head_rid"] = _put(s["q_head_rid"], fi, succ)
+        s["q_head_pos"] = _bump(s["q_head_pos"], fi, 1)
+        s["q_len"] = _bump(s["q_len"], fi, -1)
         return s, rid
 
     def arm_timer(self, s, fn, rid, t, pushed, on):
@@ -565,12 +577,12 @@ class EngineCtx:
         fc = jnp.clip(fn, 0, self.F - 1)
         rail_head = s["tmr_pos"][fc] == s["arr_cnt"][fc] - 1
         s = dict(s)
-        s["tmr_next"] = s["tmr_next"].at[
-            _gidx(on & rail_head & pushed, fn, self.F)].set(
-            t + self.threshold, mode="drop")
-        s["tmr_pos"] = s["tmr_pos"].at[
-            _gidx(on & rail_head & ~pushed, fn, self.F)].add(
-            1, mode="drop")
+        s["tmr_next"] = _put(s["tmr_next"],
+                             _gidx(on & rail_head & pushed, fn, self.F),
+                             t + self.threshold)
+        s["tmr_pos"] = _bump(s["tmr_pos"],
+                             _gidx(on & rail_head & ~pushed, fn, self.F),
+                             1)
         return s
 
 
@@ -632,12 +644,10 @@ class ResilCtx(EngineCtx):
             h = s["q_head_rid"][fc]
             hsucc = s["nxt"][jnp.clip(h, 0, self.N - 1)]
             fi = _gidx(evict, fn, self.F)
-            s["q_head_rid"] = s["q_head_rid"].at[fi].set(hsucc,
-                                                         mode="drop")
-            s["q_len"] = s["q_len"].at[fi].add(-1, mode="drop")
+            s["q_head_rid"] = _put(s["q_head_rid"], fi, hsucc)
+            s["q_len"] = _bump(s["q_len"], fi, -1)
             ev_i = evict.astype(jnp.int32)
-            s["ci"] = s["ci"].at[jnp.array([CI_SHED, CI_TERM])].add(
-                jnp.stack([ev_i, ev_i]))
+            s["ci"] = _bumps(s["ci"], {CI_SHED: ev_i, CI_TERM: ev_i})
             do = on
             was_empty = (len0 - ev_i) == 0
         else:
@@ -645,21 +655,18 @@ class ResilCtx(EngineCtx):
             was_empty = len0 == 0
             if mode == 1:  # shed the arriving request
                 sh_i = (on & full).astype(jnp.int32)
-                s["ci"] = s["ci"].at[jnp.array([CI_SHED, CI_TERM])].add(
-                    jnp.stack([sh_i, sh_i]))
+                s["ci"] = _bumps(s["ci"], {CI_SHED: sh_i, CI_TERM: sh_i})
             else:
-                s["ci"] = s["ci"].at[CI_OVF].add(
-                    (on & full).astype(jnp.int32))
+                s["ci"] = _bump(s["ci"], CI_OVF, on & full)
         tail = s["q_tail_rid"][fc]
-        s["q_head_rid"] = s["q_head_rid"].at[
-            _gidx(do & was_empty, fn, self.F)].set(rid32, mode="drop")
+        s["q_head_rid"] = _put(s["q_head_rid"],
+                               _gidx(do & was_empty, fn, self.F), rid32)
         s["nxt"] = s["nxt"].at[
             _gidx(do & ~was_empty, tail, self.N)].set(rid32,
                                                       mode="drop")
-        s["q_tail_rid"] = s["q_tail_rid"].at[
-            _gidx(do, fn, self.F)].set(rid32, mode="drop")
-        s["q_len"] = s["q_len"].at[_gidx(do, fn, self.F)].add(
-            1, mode="drop")
+        s["q_tail_rid"] = _put(s["q_tail_rid"], _gidx(do, fn, self.F),
+                               rid32)
+        s["q_len"] = _bump(s["q_len"], _gidx(do, fn, self.F), 1)
         return s, do
 
     def q_consume_direct(self, s, fn, on):
@@ -673,8 +680,8 @@ class ResilCtx(EngineCtx):
         succ = s["nxt"][jnp.clip(rid, 0, self.N - 1)]
         fi = _gidx(on, fn, self.F)
         s = dict(s)
-        s["q_head_rid"] = s["q_head_rid"].at[fi].set(succ, mode="drop")
-        s["q_len"] = s["q_len"].at[fi].add(-1, mode="drop")
+        s["q_head_rid"] = _put(s["q_head_rid"], fi, succ)
+        s["q_len"] = _bump(s["q_len"], fi, -1)
         return s, rid
 
 
@@ -723,6 +730,41 @@ def _gidx(on, idx, size):
     """Guarded scatter index: ``idx`` when enabled and valid, else an
     out-of-bounds sentinel that ``mode="drop"`` discards."""
     return jnp.where(on & (idx >= 0), idx, size)
+
+
+def _hit(x, i):
+    """One-hot mask of row ``i`` over the leading axis of ``x``,
+    broadcast over its trailing axes; all-false when ``i`` is out of
+    range (the `_gidx` sentinel)."""
+    h = jnp.arange(x.shape[0], dtype=jnp.int32) == i
+    return h.reshape(h.shape + (1,) * (x.ndim - 1))
+
+
+def _put(x, i, v):
+    """``x.at[i].set(v, mode="drop")`` as a one-hot select.
+
+    Every per-event write to a small carried array (O(F), O(C), the
+    packed counters, the per-segment overlays) goes through `_put` /
+    `_bump` instead of a scatter. A select updates no buffer in place,
+    so copy insertion puts no ordering edges around it: those edges
+    are what the TPU compiler's loop analysis aborts on (see
+    `LOOP_COMPILER_OPTIONS`), and with these writes as selects the
+    no-fault single-node loop compiles even with that analysis on. It
+    writes the same bits as the scatter on every backend."""
+    return jnp.where(_hit(x, i), jnp.asarray(v, x.dtype), x)
+
+
+def _bump(x, i, v):
+    """``x.at[i].add(v, mode="drop")`` as a one-hot select (see
+    `_put`)."""
+    return jnp.where(_hit(x, i), x + jnp.asarray(v, x.dtype), x)
+
+
+def _bumps(x, deltas):
+    """`_bump` at several static indices: ``{index: value}``."""
+    for i, v in deltas.items():
+        x = _bump(x, i, v)
+    return x
 
 
 def lex_argmin(primary, secondary, valid):
@@ -815,9 +857,8 @@ def rearm_timer(ctx, s, fn, rid, t_fire, on):
     """Re-arm the (unique) blocked queue head of ``fn`` at ``t_fire``."""
     fi = _gidx(on, fn, ctx.F)
     s = dict(s)
-    s["rearm_t"] = s["rearm_t"].at[fi].set(t_fire, mode="drop")
-    s["rearm_rid"] = s["rearm_rid"].at[fi].set(
-        jnp.asarray(rid, jnp.int32), mode="drop")
+    s["rearm_t"] = _put(s["rearm_t"], fi, t_fire)
+    s["rearm_rid"] = _put(s["rearm_rid"], fi, rid)
     return s
 
 
@@ -847,11 +888,10 @@ def dispatch(ctx, s, slot, rid, t, on):
     e = ctx.exec_at(rid)
     comp = t + e
     si = _gidx(on, slot, ctx.C)
-    s["slot_state"] = s["slot_state"].at[si].set(BUSY, mode="drop")
-    s["slot_ready"] = s["slot_ready"].at[si].set(comp, mode="drop")
-    s["slot_req"] = s["slot_req"].at[si].set(
-        jnp.asarray(rid, jnp.int32), mode="drop")
-    s["slot_used"] = s["slot_used"].at[si].set(t, mode="drop")
+    s["slot_state"] = _put(s["slot_state"], si, BUSY)
+    s["slot_ready"] = _put(s["slot_ready"], si, comp)
+    s["slot_req"] = _put(s["slot_req"], si, rid)
+    s["slot_used"] = _put(s["slot_used"], si, t)
     if ctx.has_resil:
         # attempt counter: incremented when the request starts running,
         # read back at its EXEC_DONE to classify the outcome
@@ -875,10 +915,9 @@ def dispatch(ctx, s, slot, rid, t, on):
                     comp, mode="drop")
         else:
             ki = jnp.where(on, ctx.k, ctx.seg_n)
-            s["d_rid"] = s["d_rid"].at[ki].set(
-                jnp.asarray(rid, jnp.int32), mode="drop")
-            s["d_start"] = s["d_start"].at[ki].set(t, mode="drop")
-            s["d_comp"] = s["d_comp"].at[ki].set(comp, mode="drop")
+            s["d_rid"] = _put(s["d_rid"], ki, rid)
+            s["d_start"] = _put(s["d_start"], ki, t)
+            s["d_comp"] = _put(s["d_comp"], ki, comp)
     return s
 
 
@@ -895,27 +934,24 @@ def _fold_event(ctx, s):
     arr = ctx.arrival_at(rid)
     resp = comp - arr
     slow = resp / jnp.maximum(e, 1e-9)
-    cf = s["cf"]
-    cf = cf.at[jnp.array([CF_RSUM, CF_SSUM])].add(
-        jnp.stack([jnp.where(on, resp, 0.0),
-                   jnp.where(on, slow, 0.0)]))
-    cf = cf.at[CF_RMAX].max(jnp.where(on, resp, 0.0))
-    s["cf"] = cf
-    s["hist"] = s["hist"].at[
-        jnp.where(on, hist_bin(resp), jnp.int32(HIST_BINS))
-    ].add(1, mode="drop")
+    cf = _bumps(s["cf"], {CF_RSUM: jnp.where(on, resp, 0.0),
+                          CF_SSUM: jnp.where(on, slow, 0.0)})
+    s["cf"] = jnp.where(_hit(cf, CF_RMAX),
+                        jnp.maximum(cf, jnp.where(on, resp, 0.0)), cf)
+    s["hist"] = _bump(s["hist"], jnp.where(on, hist_bin(resp),
+                                           jnp.int32(HIST_BINS)), 1)
     if ctx.deadlines is not None:
         fnr = ctx.fn_at(rid)
         dl = ctx.deadlines[jnp.clip(fnr, 0, ctx.F - 1)]
-        s["dl_miss"] = s["dl_miss"].at[
-            _gidx(on & (resp > dl), fnr, ctx.F)].add(1, mode="drop")
+        s["dl_miss"] = _bump(s["dl_miss"],
+                             _gidx(on & (resp > dl), fnr, ctx.F), 1)
     if ctx.tl_bins:
         tb = jnp.clip((arr / ctx.tl_bucket).astype(jnp.int32),
                       0, ctx.tl_bins - 1)
         ti = jnp.where(on, tb, jnp.int32(ctx.tl_bins))
-        s["tl_cnt"] = s["tl_cnt"].at[ti].add(1, mode="drop")
-        s["tl_resp"] = s["tl_resp"].at[ti].add(resp, mode="drop")
-        s["tl_exec"] = s["tl_exec"].at[ti].add(e, mode="drop")
+        s["tl_cnt"] = _bump(s["tl_cnt"], ti, 1)
+        s["tl_resp"] = _bump(s["tl_resp"], ti, resp)
+        s["tl_exec"] = _bump(s["tl_exec"], ti, e)
     return s
 
 
@@ -931,19 +967,18 @@ def start_cold(ctx, s, slot, fn, t, evict_fn, on):
                         ctx.t_evict[jnp.clip(evict_fn, 0, ctx.F - 1)],
                         0.0)
     si = _gidx(on, slot, ctx.C)
-    s["slot_fn"] = s["slot_fn"].at[si].set(fn, mode="drop")
-    s["slot_state"] = s["slot_state"].at[si].set(COLD, mode="drop")
-    s["slot_ready"] = s["slot_ready"].at[si].set(
-        t + ctx.t_cold[fc] + ev_cost, mode="drop")
-    s["slot_req"] = s["slot_req"].at[si].set(-1, mode="drop")
-    s["slot_used"] = s["slot_used"].at[si].set(0.0, mode="drop")
-    s["slot_seq"] = s["slot_seq"].at[si].set(s["ci"][CI_SEQ],
-                                             mode="drop")
-    on_i = on.astype(jnp.int32)
-    s["ci"] = s["ci"].at[jnp.array([CI_SEQ, CI_COLD, CI_EVICT])].add(
-        jnp.stack([on_i, on_i, evicting.astype(jnp.int32)]))
-    s["cf"] = s["cf"].at[jnp.array([CF_COLDT, CF_EVICTT])].add(
-        jnp.stack([jnp.where(on, ctx.t_cold[fc], 0.0), ev_cost]))
+    s["slot_fn"] = _put(s["slot_fn"], si, fn)
+    s["slot_state"] = _put(s["slot_state"], si, COLD)
+    s["slot_ready"] = _put(s["slot_ready"], si,
+                           t + ctx.t_cold[fc] + ev_cost)
+    s["slot_req"] = _put(s["slot_req"], si, -1)
+    s["slot_used"] = _put(s["slot_used"], si, 0.0)
+    s["slot_seq"] = _put(s["slot_seq"], si, s["ci"][CI_SEQ])
+    s["ci"] = _bumps(s["ci"], {CI_SEQ: on, CI_COLD: on,
+                               CI_EVICT: evicting})
+    s["cf"] = _bumps(s["cf"], {CF_COLDT: jnp.where(on, ctx.t_cold[fc],
+                                                   0.0),
+                               CF_EVICTT: ev_cost})
     return s
 
 
@@ -1294,19 +1329,17 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             ji = _gidx(exec_on, j_done, F)
             exec_i = exec_on.astype(jnp.int32)
             s = dict(s)
-            s["slot_state"] = s["slot_state"].at[si].set(IDLE,
-                                                         mode="drop")
-            s["slot_ready"] = s["slot_ready"].at[si].set(BIG,
-                                                         mode="drop")
-            s["slot_req"] = s["slot_req"].at[si].set(-1, mode="drop")
+            s["slot_state"] = _put(s["slot_state"], si, IDLE)
+            s["slot_ready"] = _put(s["slot_ready"], si, BIG)
+            s["slot_req"] = _put(s["slot_req"], si, -1)
             # estimator sees the completion before the policy reacts
-            s["est_sum"] = s["est_sum"].at[ji].add(e_done, mode="drop")
-            s["est_n"] = s["est_n"].at[ji].add(1, mode="drop")
-            s["cf"] = s["cf"].at[CF_GSUM].add(
-                jnp.where(exec_on, e_done, 0.0))
+            s["est_sum"] = _bump(s["est_sum"], ji, e_done)
+            s["est_n"] = _bump(s["est_n"], ji, 1)
+            s["cf"] = _bump(s["cf"], CF_GSUM,
+                            jnp.where(exec_on, e_done, 0.0))
             if not has_resil:
-                s["ci"] = s["ci"].at[jnp.array([CI_GN, CI_DONE])].add(
-                    jnp.stack([exec_i, exec_i]))
+                s["ci"] = _bumps(s["ci"], {CI_GN: exec_i,
+                                           CI_DONE: exec_i})
             else:
                 # outcome of this attempt: the estimator observed the
                 # attempt above (every attempt burns real slot time);
@@ -1319,14 +1352,11 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 retry_d = fail_d & ~exh_d
                 tmo_d = ctx.tmo_at(rid_done)
                 ok_i = ok_d.astype(jnp.int32)
-                s["ci"] = s["ci"].at[jnp.array(
-                    [CI_GN, CI_DONE, CI_TERM, CI_FAILED, CI_TMO,
-                     CI_RETRY, CI_EXH])].add(jnp.stack(
-                    [exec_i, ok_i, ok_i + exh_d.astype(jnp.int32),
-                     (fail_d & ~tmo_d).astype(jnp.int32),
-                     (fail_d & tmo_d).astype(jnp.int32),
-                     retry_d.astype(jnp.int32),
-                     exh_d.astype(jnp.int32)]))
+                s["ci"] = _bumps(s["ci"], {
+                    CI_GN: exec_i, CI_DONE: ok_i,
+                    CI_TERM: ok_i + exh_d.astype(jnp.int32),
+                    CI_FAILED: fail_d & ~tmo_d, CI_TMO: fail_d & tmo_d,
+                    CI_RETRY: retry_d, CI_EXH: exh_d})
                 # fold (and exact-record) successful completions only
                 rd32 = jnp.asarray(rid_done, jnp.int32)
                 s["ev_rid"] = jnp.where(ok_d, rd32, s["ev_rid"])
@@ -1376,13 +1406,13 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 oi = _gidx(fire_orig, f_o, F)
                 rid_r = s["rearm_rid"][f_r]
                 s = dict(s)
-                s["tmr_pos"] = s["tmr_pos"].at[oi].add(1, mode="drop")
-                s["tmr_next"] = s["tmr_next"].at[oi].set(
+                s["tmr_pos"] = _bump(s["tmr_pos"], oi, 1)
+                s["tmr_next"] = _put(
+                    s["tmr_next"], oi,
                     jnp.where(more, ctx.arrival_at(succ) + threshold,
-                              BIG),
-                    mode="drop")
-                s["rearm_t"] = s["rearm_t"].at[
-                    _gidx(fire_re, f_r, F)].set(BIG, mode="drop")
+                              BIG))
+                s["rearm_t"] = _put(s["rearm_t"], _gidx(fire_re, f_r, F),
+                                    BIG)
                 rid_t = jnp.where(fire_orig, rid_o, rid_r)
                 s = kernel.on_timer(ctx, s, rid_t, t_ev, ev_timer)
 
@@ -1415,15 +1445,14 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             # ---------------------------------------------------- arrival
             s = dict(s)
             if kernel.has_timers:
-                s["arr_cnt"] = s["arr_cnt"].at[
-                    _gidx(ev_arr, ctx.fn_at(rid_a), F)].add(
-                    1, mode="drop")
+                s["arr_cnt"] = _bump(s["arr_cnt"],
+                                     _gidx(ev_arr, ctx.fn_at(rid_a), F),
+                                     1)
             # n_events counts processed events (parked no-op spins are
             # excluded, so the count is window-size invariant)
             progress = ev_slot | ev_timer | ev_arr | ev_rtry
-            s["ci"] = s["ci"].at[jnp.array([CI_NEXT, CI_ITERS])].add(
-                jnp.stack([ev_arr.astype(jnp.int32),
-                           progress.astype(jnp.int32)]))
+            s["ci"] = _bumps(s["ci"], {CI_NEXT: ev_arr,
+                                       CI_ITERS: progress})
             s = kernel.on_arrival(ctx, s, rid_na, t_na,
                                   ev_arr | ev_rtry)
 
@@ -1476,13 +1505,13 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 rec_f = jnp.stack([
                     t_ev, jnp.where(exec_on, e_done, 0.0)])
                 ki = jnp.where(progress, k, SEG)
-                s["tr_i"] = s["tr_i"].at[ki].set(rec_i, mode="drop")
-                s["tr_f"] = s["tr_f"].at[ki].set(rec_f, mode="drop")
+                s["tr_i"] = _put(s["tr_i"], ki, rec_i)
+                s["tr_f"] = _put(s["tr_f"], ki, rec_f)
             stall = jnp.where(
                 active & ~live, 1,
                 jnp.where(active & (s["ci"][CI_ITERS] >= max_iters), 2,
                           s["ci"][CI_STALL]))
-            s["ci"] = s["ci"].at[CI_STALL].set(stall)
+            s["ci"] = _put(s["ci"], CI_STALL, stall)
             return s
 
         step_lanes = jax.vmap(
@@ -1623,7 +1652,8 @@ def simulate_policy_from_trace(trace: Trace, policy: str, capacity: int,
                    static_argnames=("kernel", "n_fns", "capacity",
                                     "queue_cap", "stream", "window",
                                     "tl_bins", "keep_responses",
-                                    "resil", "trace"))
+                                    "resil", "trace"),
+                   compiler_options=LOOP_COMPILER_OPTIONS)
 def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                    threshold, n_live=None, deadlines=None,
                    rs_nfail=None, rs_tmo=None, rs_key=None, *, kernel,
@@ -1689,7 +1719,8 @@ def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                cold_time=out["cold_time"],
                evictions=out["evictions"],
                overflow=out["overflow"],
-               stalled=out["stalled"])
+               stalled=out["stalled"],
+               n_events=out["n_events"])
     if tl_bins:
         res["tl_count"] = out["tl_count"]
         res["tl_resp_sum"] = out["tl_resp_sum"]

@@ -49,7 +49,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.jax_engine import (BIG, COLD, IDLE, EngineCtx,
-                                   PolicyKernel, _gidx, arm_timer,
+                                   PolicyKernel, _bump, _gidx, _put,
+                                   arm_timer,
                                    cold_counts, dispatch, est_means,
                                    k_counts, lex_argmin, pick_idle_own,
                                    q_consume_direct, q_head, q_pop,
@@ -270,8 +271,8 @@ class FaasCacheKernel(CentralQueueKernel):
                 + (s["slot_freq"][sc] + 1.0) * ctx.t_cold[fn])
         si = _gidx(on, slot, ctx.C)
         s = dict(s)
-        s["slot_freq"] = s["slot_freq"].at[si].add(1, mode="drop")
-        s["slot_prio"] = s["slot_prio"].at[si].set(prio, mode="drop")
+        s["slot_freq"] = _bump(s["slot_freq"], si, 1)
+        s["slot_prio"] = _put(s["slot_prio"], si, prio)
         return dispatch(ctx, s, slot, rid, t, on)
 
     def _victim_key(self, ctx, s):
@@ -287,8 +288,8 @@ class FaasCacheKernel(CentralQueueKernel):
     def _start_cold(self, ctx, s, slot, fn, t, evict_fn, on):
         s = start_cold(ctx, s, slot, fn, t, evict_fn, on)
         si = _gidx(on, slot, ctx.C)
-        s["slot_freq"] = s["slot_freq"].at[si].set(0, mode="drop")
-        s["slot_prio"] = s["slot_prio"].at[si].set(0.0, mode="drop")
+        s["slot_freq"] = _put(s["slot_freq"], si, 0)
+        s["slot_prio"] = _put(s["slot_prio"], si, 0.0)
         return s
 
 

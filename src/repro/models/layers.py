@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.utils.compat import shard_map
-
 Params = Dict[str, Any]
 Specs = Dict[str, Any]
 
@@ -135,7 +133,7 @@ def tp_einsum(eq: str, x, w, sharder, *, w_model_dim=None,
             y = lax.psum(y.astype(xl.dtype), tp)
         return y
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(spec(x.ndim, x_model_dim, batched=True),
                   spec(w.ndim, w_model_dim)),
@@ -196,7 +194,7 @@ def seq_parallel_attention(q, k, v, sharder, *, chunk: int,
                               q_offset_dyn=off)
         return y
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dp, None, None, None),) * 3,
         out_specs=P(dp, tp, None, None),
@@ -595,7 +593,7 @@ def moe_apply_ep_shardmap(params: Params, cfg, x, sharder, capacity: int):
             aux = lax.pmean(aux, dp)          # P() out_spec needs global
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(dp, None, None), P(), P(tp, None, None),
                   P(tp, None, None), P(tp, None, None)),

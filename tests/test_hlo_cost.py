@@ -1,12 +1,6 @@
 """The HLO cost analyzer must agree with XLA on loop-free programs and
 correctly multiply while-loop trip counts (which XLA's cost_analysis does
 NOT — the motivating bug)."""
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -14,21 +8,9 @@ from jax import lax
 
 from repro.roofline.hlo_cost import analyze_hlo
 
-# The XLA-comparison cases run in a subprocess with default XLA_FLAGS:
-# importing repro.core.jax_engine (which pytest collection does via the
-# engine test modules) sets --xla_cpu_use_thunk_runtime=false before
-# the CPU client initialises, and under that legacy runtime XLA:CPU
-# lowers matmuls to oneDNN custom-calls whose cost_analysis reports
-# flops=-1 — there is nothing to agree with in-process.
-_XLA_SCRIPT = textwrap.dedent("""
-    import json
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
 
-    from repro.roofline.hlo_cost import analyze_hlo
-    from repro.utils.compat import compiled_cost_analysis
-
+@pytest.fixture(scope="module")
+def xla_flops():
     def scanned(a):
         y, _ = lax.scan(lambda c, _: (c @ c, None), a, None, length=12)
         return y
@@ -46,23 +28,8 @@ _XLA_SCRIPT = textwrap.dedent("""
     for name, (fn, args) in cases.items():
         c = jax.jit(fn).lower(*args).compile()
         out[name] = dict(mine=analyze_hlo(c.as_text()).flops,
-                         theirs=compiled_cost_analysis(c)["flops"])
-    print("RESULT" + json.dumps(out))
-""")
-
-
-@pytest.fixture(scope="module")
-def xla_flops():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _XLA_SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=300,
-                       cwd=os.path.dirname(os.path.dirname(__file__)))
-    assert r.returncode == 0, r.stderr[-3000:]
-    line = [l for l in r.stdout.splitlines()
-            if l.startswith("RESULT")][0]
-    return json.loads(line[len("RESULT"):])
+                         theirs=c.cost_analysis()["flops"])
+    return out
 
 
 def test_matches_xla_on_plain_matmul(xla_flops):

@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core import simulate
-from repro.core.jax_engine import (HIST_PER_DECADE, hist_edges,
+from repro.core.jax_engine import (HIST_PER_DECADE, LANE_CHUNKS,
+                                   default_lane_chunk, hist_edges,
                                    resolve_lane_chunk,
                                    simulate_policy_from_trace,
                                    simulate_policy_jax, sweep)
@@ -223,6 +224,16 @@ def test_resolve_lane_chunk_auto_probe_is_cached():
     assert resolve_lane_chunk("auto") == c1      # cached, no re-probe
     assert resolve_lane_chunk(7) == 7
     assert resolve_lane_chunk("") >= 1           # backend table
+
+
+@pytest.mark.parametrize("backend", sorted(LANE_CHUNKS))
+def test_default_lane_chunk_reads_the_table(backend):
+    assert default_lane_chunk(backend) == LANE_CHUNKS[backend]
+
+
+def test_default_lane_chunk_rejects_unknown_backend():
+    with pytest.raises(ValueError, match="no LANE_CHUNKS entry"):
+        default_lane_chunk("metal")
 
 
 def test_timeline_fold_matches_python_timeline():

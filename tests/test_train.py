@@ -116,14 +116,13 @@ def test_psum_int8_single_device():
     # axis of size 1: psum_int8 must be a (quantised) identity
     from jax.sharding import Mesh
     import jax.numpy as jnp
-    from repro.utils.compat import shard_map
     mesh = jax.make_mesh((1,), ("d",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(1024,)),
                     jnp.float32)
     out = jax.jit(
-        shard_map(lambda v: psum_int8(v, "d"), mesh=mesh,
-                  in_specs=jax.sharding.PartitionSpec(),
-                  out_specs=jax.sharding.PartitionSpec()))(x)
+        jax.shard_map(lambda v: psum_int8(v, "d"), mesh=mesh,
+                      in_specs=jax.sharding.PartitionSpec(),
+                      out_specs=jax.sharding.PartitionSpec()))(x)
     err = np.abs(np.asarray(out) - np.asarray(x))
     bound = np.abs(np.asarray(x)).reshape(-1, 256).max(1) / 127.0
     assert (err.reshape(-1, 256) <= bound[:, None] + 1e-6).all()

@@ -1,11 +1,10 @@
-"""Telemetry overhead and profiling-hook microbench.
+"""Telemetry overhead microbench.
 
 Times the same grid with tracing off and on, reports the in-loop
 trace rail's overhead (the disabled path is *bitwise free* — gated in
 ``--smoke`` — so the interesting number is the enabled path's cost:
 one record scatter per event plus one ordered host flush per
-segment), and exercises the profiling hooks: AOT phase breakdown of
-the traced engine call and run provenance for the BENCH report.
+segment), and records run provenance for the BENCH report.
 
     PYTHONPATH=src python -m benchmarks.telemetry_bench [--n N]
 """

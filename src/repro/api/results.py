@@ -260,6 +260,13 @@ class ResultSet:
         runs — a broken conservation identity
         ``done + shed + failed_exhausted != n_requests``. Every error
         names the offending cells by their full spec coordinate."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("repro.check"):
+            self._check()
+        return self
+
+    def _check(self) -> None:
         resil = self.meta.get("resilience") or None
         for m in HEALTH_METRICS:
             if m not in self.data:
@@ -292,7 +299,6 @@ class ResultSet:
                         f"break conservation (done + shed + "
                         f"failed_exhausted != n_requests={n}): "
                         f"{self._bad_cells(bad)}")
-        return self
 
     # -------------------------------------------------------- npz io
     def save_npz(self, path) -> None:

@@ -103,86 +103,108 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
     A spec with a ``cluster`` axis is delegated to
     `repro.cluster.runner.run_cluster_experiment`, which stacks one
     (policy, trace, capacity, beta) grid per cluster topology into the
-    ResultSet's trailing ``cluster`` dim."""
-    import jax
-    import jax.numpy as jnp
+    ResultSet's trailing ``cluster`` dim.
 
-    from repro.core.jax_engine import _sweep_metrics, resolve_lane_chunk
+    The single-node path writes host spans into an active
+    `jax.profiler` trace: ``repro.run_experiment`` around the call,
+    ``repro.lower``, one ``repro.dispatch`` and one ``repro.fetch``
+    per chunk, ``repro.assemble``, and ``repro.check`` in
+    `ResultSet.check` (docs/observability.md, "Performance spans").
+    Their stats are computed only while a trace is active."""
+    from jax.profiler import TraceAnnotation
 
     if spec.cluster is not None:
         from repro.cluster.runner import run_cluster_experiment
         return run_cluster_experiment(spec)
+    with TraceAnnotation("repro.run_experiment") as span:
+        out = _run_single_node(spec)
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(chunks=len(out.meta["loop_steps"]),
+                              lanes=int(out.computed.sum()))
+    return out
+
+
+def _run_single_node(spec: ExperimentSpec) -> ResultSet:
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation
+
+    from repro.core.jax_engine import _sweep_metrics, resolve_lane_chunk
 
     spec.validate()
-    sources, stacked, F, N = _lower_grid(spec)
-    rs = spec.resilience_ops(stacked, F)
-    resil = None
-    if rs is not None:
-        # faults on: the effective (timeout-clipped) exec times replace
-        # the exec operand; the pre-planned outcome operands ride the
-        # same per-device / per-row slicing as the trace operands
-        eff, rs_nfail, rs_tmo, rs_key, resil = rs
-        stacked = dict(stacked, exec_time=eff)
-    T = len(sources)
-    C = max(spec.capacities)
-    masks = np.stack([np.arange(C) < c for c in spec.capacities])
-    chunk = resolve_lane_chunk(spec.lane_chunk)
-    row_split = T > 1 and T * N > ROW_SPLIT_ELEMS
-    plan, K, B = _chunk_plan(spec, T, chunk, row_split)
+    with TraceAnnotation("repro.lower") as span:
+        sources, stacked, F, N = _lower_grid(spec)
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(n_requests=N, n_functions=F)
+        rs = spec.resilience_ops(stacked, F)
+        resil = None
+        if rs is not None:
+            # faults on: the effective (timeout-clipped) exec times
+            # replace the exec operand; the pre-planned outcome
+            # operands ride the same per-device / per-row slicing as
+            # the trace operands
+            eff, rs_nfail, rs_tmo, rs_key, resil = rs
+            stacked = dict(stacked, exec_time=eff)
+        T = len(sources)
+        C = max(spec.capacities)
+        masks = np.stack([np.arange(C) < c for c in spec.capacities])
+        chunk = resolve_lane_chunk(spec.lane_chunk)
+        row_split = T > 1 and T * N > ROW_SPLIT_ELEMS
+        plan, K, B = _chunk_plan(spec, T, chunk, row_split)
 
-    host_i, host_n = spec.host_shard
-    mine = [ci for ci in range(len(plan)) if ci % host_n == host_i]
-    if not mine:
-        raise ValueError(
-            f"ExperimentSpec: host_shard={spec.host_shard} gets no "
-            f"chunks (the grid lowers to {len(plan)} chunk(s) of "
-            f"{chunk} lanes — lower host count or lane_chunk)")
-
-    devs = jax.local_devices()
-    if spec.devices is not None:
-        if spec.devices > len(devs):
+        host_i, host_n = spec.host_shard
+        mine = [ci for ci in range(len(plan)) if ci % host_n == host_i]
+        if not mine:
             raise ValueError(
-                f"ExperimentSpec: devices={spec.devices} but only "
-                f"{len(devs)} local device(s) present")
-        devs = devs[: spec.devices]
-    if spec.trace_events:
-        # traced chunks run serially on the default device so the
-        # ordered-callback flushes of different chunks cannot
-        # interleave in one collect scope
-        devs = devs[:1]
-    multi_dev = len(devs) > 1
+                f"ExperimentSpec: host_shard={spec.host_shard} gets no "
+                f"chunks (the grid lowers to {len(plan)} chunk(s) of "
+                f"{chunk} lanes — lower host count or lane_chunk)")
 
-    # shared (T, ...) trace operands — one committed copy per device
-    # (a single uncommitted copy when not sharding, matching the legacy
-    # single-device path exactly)
-    shared0 = {k: jnp.asarray(v) for k, v in stacked.items()}
-    if rs is not None:
-        shared0["rs_nfail"] = jnp.asarray(rs_nfail, jnp.int32)
-        shared0["rs_tmo"] = jnp.asarray(rs_tmo)
-        shared0["rs_key"] = jnp.asarray(rs_key, jnp.int32)
-    if multi_dev:
-        shared_per_dev = [
-            {k: jax.device_put(v, d) for k, v in shared0.items()}
-            for d in devs]
-    else:
-        shared_per_dev = [shared0]
+        devs = jax.local_devices()
+        if spec.devices is not None:
+            if spec.devices > len(devs):
+                raise ValueError(
+                    f"ExperimentSpec: devices={spec.devices} but only "
+                    f"{len(devs)} local device(s) present")
+            devs = devs[: spec.devices]
+        if spec.trace_events:
+            # traced chunks run serially on the default device so the
+            # ordered-callback flushes of different chunks cannot
+            # interleave in one collect scope
+            devs = devs[:1]
+        multi_dev = len(devs) > 1
 
-    kernels = {p: get_kernel(p) for p in spec.policies}
-    dl = spec.deadline_ops(F)
-    dl_op = None if dl is None else jnp.asarray(dl)
+        # shared (T, ...) trace operands — one committed copy per
+        # device (a single uncommitted copy when not sharding, matching
+        # the legacy single-device path exactly)
+        shared0 = {k: jnp.asarray(v) for k, v in stacked.items()}
+        if rs is not None:
+            shared0["rs_nfail"] = jnp.asarray(rs_nfail, jnp.int32)
+            shared0["rs_tmo"] = jnp.asarray(rs_tmo)
+            shared0["rs_key"] = jnp.asarray(rs_key, jnp.int32)
+        if multi_dev:
+            shared_per_dev = [
+                {k: jax.device_put(v, d) for k, v in shared0.items()}
+                for d in devs]
+        else:
+            shared_per_dev = [shared0]
 
-    # per-policy lane coordinate columns (identical for every policy:
-    # betas=None resolves per kernel at chunk build time)
-    tix_col = np.repeat(np.arange(T, dtype=np.int32), K * B)
-    mask_col = np.tile(np.repeat(masks, B, axis=0), (T, 1))
+        kernels = {p: get_kernel(p) for p in spec.policies}
+        dl = spec.deadline_ops(F)
+        dl_op = None if dl is None else jnp.asarray(dl)
 
-    def beta_col(policy: str) -> np.ndarray:
-        bs = np.asarray(
-            [kernels[policy].default_beta] if spec.betas is None
-            else list(spec.betas), np.float64)
-        return np.tile(bs, T * K)
+        # per-policy lane coordinate columns (identical for every
+        # policy: betas=None resolves per kernel at chunk build time)
+        tix_col = np.repeat(np.arange(T, dtype=np.int32), K * B)
+        mask_col = np.tile(np.repeat(masks, B, axis=0), (T, 1))
 
-    beta_cols = {p: beta_col(p) for p in spec.policies}
+        def beta_col(policy: str) -> np.ndarray:
+            bs = np.asarray(
+                [kernels[policy].default_beta] if spec.betas is None
+                else list(spec.betas), np.float64)
+            return np.tile(bs, T * K)
+
+        beta_cols = {p: beta_col(p) for p in spec.policies}
 
     def run_chunk(ci: int):
         pi, lo, hi = plan[ci]
@@ -203,32 +225,43 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
             tix_l = jax.device_put(tix_l, dev)
             mask_l = jax.device_put(mask_l, dev)
             beta_l = jax.device_put(beta_l, dev)
-        out = _sweep_metrics(
-            sh["fn_id"], sh["arrival"], sh["exec_time"],
-            sh["cold_start"], sh["evict"], tix_l, mask_l, beta_l,
-            jnp.float64(spec.prior), jnp.float64(spec.threshold),
-            deadlines=dl_op,
-            rs_nfail=sh.get("rs_nfail"), rs_tmo=sh.get("rs_tmo"),
-            rs_key=sh.get("rs_key"), resil=resil,
-            kernel=kernels[policy], n_fns=F, capacity=C,
-            queue_cap=spec.queue_cap, stream=spec.stream,
-            window=spec.window, tl_bins=spec.tl_bins,
-            tl_bucket=spec.tl_bucket,
-            keep_responses=spec.keep_per_request,
-            trace=spec.trace_events)
+        with TraceAnnotation("repro.dispatch") as span:
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(chunk=ci, policy=policy, lanes=hi - lo)
+            out = _sweep_metrics(
+                sh["fn_id"], sh["arrival"], sh["exec_time"],
+                sh["cold_start"], sh["evict"], tix_l, mask_l, beta_l,
+                jnp.float64(spec.prior), jnp.float64(spec.threshold),
+                deadlines=dl_op,
+                rs_nfail=sh.get("rs_nfail"), rs_tmo=sh.get("rs_tmo"),
+                rs_key=sh.get("rs_key"), resil=resil,
+                kernel=kernels[policy], n_fns=F, capacity=C,
+                queue_cap=spec.queue_cap, stream=spec.stream,
+                window=spec.window, tl_bins=spec.tl_bins,
+                tl_bucket=spec.tl_bucket,
+                keep_responses=spec.keep_per_request,
+                trace=spec.trace_events)
         placed = sorted({f"{d.platform}:{d.id}" for v in out.values()
                          for d in v.devices()})
-        return ci, jax.device_get(out), placed
+        with TraceAnnotation("repro.fetch") as span:
+            out = jax.device_get(out)
+            steps = int(out.pop("loop_steps"))
+            if TraceAnnotation.is_enabled():
+                span.set_metadata(
+                    chunk=ci, lanes=hi - lo, loop_steps=steps,
+                    lane_events=int(np.sum(out["n_events"],
+                                           dtype=np.int64)))
+        return ci, out, placed, steps
 
     if spec.trace_events:
         # one collect scope per chunk: device_get inside run_chunk
         # blocks, so every ordered flush lands before the scope closes
         from repro.telemetry import rail
-        outs, placed = {}, {}
+        outs, placed, steps = {}, {}, {}
         lane_events: Dict[tuple, dict] = {}
         for ci in mine:
             with rail.collect() as sink:
-                _, outs[ci], placed[ci] = run_chunk(ci)
+                _, outs[ci], placed[ci], steps[ci] = run_chunk(ci)
             pi, lo, hi = plan[ci]
             for j in range(hi - lo):
                 lane_events[(pi, lo + j)] = sink.lane_events(j)
@@ -240,70 +273,75 @@ def run_experiment(spec: ExperimentSpec) -> ResultSet:
         workers = max(2, len(devs))
         with ThreadPoolExecutor(max_workers=workers) as tp:
             done = list(tp.map(run_chunk, mine))
-        outs = {ci: out for ci, out, _ in done}
-        placed = {ci: ids for ci, _, ids in done}
+        outs = {ci: out for ci, out, _, _ in done}
+        placed = {ci: ids for ci, _, ids, _ in done}
+        steps = {ci: n for ci, _, _, n in done}
 
     # ------------------------------------------------------- assembly
-    P = len(spec.policies)
-    lanes_per_policy = T * K * B
-    flat: Dict[str, np.ndarray] = {}
-    computed = np.zeros((P, lanes_per_policy), bool)
-    for ci in mine:
-        pi, lo, hi = plan[ci]
-        out = outs[ci]
-        for k, v in out.items():
-            v = np.asarray(v)
-            if k not in flat:
-                flat[k] = np.zeros((P, lanes_per_policy) + v.shape[1:],
-                                   v.dtype)
-            flat[k][pi, lo:hi] = v
-        computed[pi, lo:hi] = True
+    with TraceAnnotation("repro.assemble"):
+        P = len(spec.policies)
+        lanes_per_policy = T * K * B
+        flat: Dict[str, np.ndarray] = {}
+        computed = np.zeros((P, lanes_per_policy), bool)
+        for ci in mine:
+            pi, lo, hi = plan[ci]
+            out = outs[ci]
+            for k, v in out.items():
+                v = np.asarray(v)
+                if k not in flat:
+                    flat[k] = np.zeros(
+                        (P, lanes_per_policy) + v.shape[1:], v.dtype)
+                flat[k][pi, lo:hi] = v
+            computed[pi, lo:hi] = True
 
-    grid = lambda a: a.reshape((P, T, K, B) + a.shape[2:])  # noqa: E731
-    data = {k: grid(v) for k, v in flat.items()}
-    if dl is not None:
-        from repro.core.jax_engine import slo_attainment
-        data["slo_attainment"] = slo_attainment(
-            data["deadline_miss"], data["done"])
-    if resil is not None:
-        from repro.core.jax_engine import goodput
-        data["goodput"] = goodput(data["done"], N)
-    beta_coord = (list(spec.betas) if spec.betas is not None
-                  else [_BETA_DEFAULT])
-    coords = dict(policy=list(spec.policies),
-                  trace=_unique_labels([s.label for s in sources]),
-                  capacity=list(spec.capacities),
-                  beta=beta_coord)
-    meta = dict(spec.meta,
-                n_requests=N, n_functions=F, queue_cap=spec.queue_cap,
-                stream=spec.stream, window=spec.window,
-                tl_bins=spec.tl_bins, tl_bucket=spec.tl_bucket,
-                prior=spec.prior, threshold=spec.threshold,
-                lane_chunk=chunk, host_shard=list(spec.host_shard),
-                row_split=row_split,
-                deadlines=(None if dl is None else
-                           (spec.deadlines
-                            if isinstance(spec.deadlines, float)
-                            else list(spec.deadlines))),
-                n_devices=len(devs), backend=jax.default_backend(),
-                chunk_devices=[placed[ci] for ci in mine],
-                resilience=spec.resilience_meta(),
-                seeds=(list(spec.seeds) if spec.seeds is not None
-                       else None),
-                trace_events=spec.trace_events,
-                default_betas={p: kernels[p].default_beta
-                               for p in spec.policies})
-    trace_run = None
-    if spec.trace_events:
-        from repro.telemetry.spans import TraceRun
-        trace_run = TraceRun(coords)
-        for (pi, lane), ev in lane_events.items():
-            t_i, rest = divmod(lane, K * B)
-            kc, b = divmod(rest, B)
-            trace_run.add_cell((pi, t_i, kc, b), ev)
-    return ResultSet(data=data, coords=coords,
-                     computed=grid(computed), meta=meta,
-                     trace=trace_run)
+        grid = lambda a: a.reshape(  # noqa: E731
+            (P, T, K, B) + a.shape[2:])
+        data = {k: grid(v) for k, v in flat.items()}
+        if dl is not None:
+            from repro.core.jax_engine import slo_attainment
+            data["slo_attainment"] = slo_attainment(
+                data["deadline_miss"], data["done"])
+        if resil is not None:
+            from repro.core.jax_engine import goodput
+            data["goodput"] = goodput(data["done"], N)
+        beta_coord = (list(spec.betas) if spec.betas is not None
+                      else [_BETA_DEFAULT])
+        coords = dict(policy=list(spec.policies),
+                      trace=_unique_labels([s.label for s in sources]),
+                      capacity=list(spec.capacities),
+                      beta=beta_coord)
+        meta = dict(spec.meta,
+                    n_requests=N, n_functions=F,
+                    queue_cap=spec.queue_cap,
+                    stream=spec.stream, window=spec.window,
+                    tl_bins=spec.tl_bins, tl_bucket=spec.tl_bucket,
+                    prior=spec.prior, threshold=spec.threshold,
+                    lane_chunk=chunk, host_shard=list(spec.host_shard),
+                    row_split=row_split,
+                    deadlines=(None if dl is None else
+                               (spec.deadlines
+                                if isinstance(spec.deadlines, float)
+                                else list(spec.deadlines))),
+                    n_devices=len(devs), backend=jax.default_backend(),
+                    chunk_devices=[placed[ci] for ci in mine],
+                    loop_steps=[steps[ci] for ci in mine],
+                    resilience=spec.resilience_meta(),
+                    seeds=(list(spec.seeds) if spec.seeds is not None
+                           else None),
+                    trace_events=spec.trace_events,
+                    default_betas={p: kernels[p].default_beta
+                                   for p in spec.policies})
+        trace_run = None
+        if spec.trace_events:
+            from repro.telemetry.spans import TraceRun
+            trace_run = TraceRun(coords)
+            for (pi, lane), ev in lane_events.items():
+                t_i, rest = divmod(lane, K * B)
+                kc, b = divmod(rest, B)
+                trace_run.add_cell((pi, t_i, kc, b), ev)
+        return ResultSet(data=data, coords=coords,
+                         computed=grid(computed), meta=meta,
+                         trace=trace_run)
 
 
 # short alias — `from repro.api import run`

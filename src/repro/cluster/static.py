@@ -331,6 +331,7 @@ def run_static_entry(spec, entry: ClusterSpec,
                                                   []).append(ev)
                     else:
                         out = call()
+                    out.pop("loop_steps")
                     for m, v in out.items():
                         row_outs.setdefault(m, []).append(
                             np.asarray(v))

@@ -121,8 +121,12 @@ window. Parking preserves each lane's event order exactly: a lane only
 parks when its true earliest pending event is the out-of-window
 arrival. The per-lane window cursor is implicit in the arrival cursor
 (``ci[CI_NEXT] // W``); ``n_events`` counts *processed events*, so it
-is window-size invariant. The queue-successor gathers use a second,
-window-major positional layout (stable argsort of (rid // W, fn)) with
+is window-size invariant. ``loop_steps`` (one scalar per launch)
+counts what the device ran instead: SEG lane-stacked iterations per
+segment, parked spins and finished lanes included, so the sum of a
+launch's ``n_events`` over ``loop_steps`` x L is its lane occupancy.
+The queue-successor gathers use a second, window-major positional
+layout (stable argsort of (rid // W, fn)) with
 per-window per-function offsets (``off_w`` / ``cum_cnt``) so in-window
 position reads are slab-local.
 
@@ -1214,6 +1218,10 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
         s["tr_i"] = jnp.full((L, SEG, TR_RI), -1, jnp.int32)
         s["tr_f"] = jnp.zeros((L, SEG, TR_RF), jnp.float64)
     s.update(kernel.extra_state(L, C, F))
+    # lane-stacked loop iterations the device runs: SEG per segment,
+    # counting parked spins and finished lanes (`n_events` counts only
+    # processed events)
+    s["loop_steps"] = jnp.int32(0)
 
     max_iters = (256 * N + 4096) * (max_att if has_resil else 1)
     n_slot = 2 * C   # candidate positions: busy slots then cold slots
@@ -1383,9 +1391,10 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 s["r_fire"] = jnp.where(retry_d & r_empty, elig,
                                         s["r_fire"])
                 s["r_len"] = s["r_len"] + retry_d.astype(jnp.int32)
-            s = kernel.on_cold_done(ctx, s, slot, t_ev, cold_on)
-            s = kernel.on_exec_done(ctx, s, slot, rid_done, t_ev,
-                                    exec_on)
+            with jax.named_scope("repro.policy"):
+                s = kernel.on_cold_done(ctx, s, slot, t_ev, cold_on)
+                s = kernel.on_exec_done(ctx, s, slot, rid_done, t_ev,
+                                        exec_on)
 
             # ------------------------------------------------ timer event
             ev_timer = jnp.bool_(False)
@@ -1414,7 +1423,8 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 s["rearm_t"] = _put(s["rearm_t"], _gidx(fire_re, f_r, F),
                                     BIG)
                 rid_t = jnp.where(fire_orig, rid_o, rid_r)
-                s = kernel.on_timer(ctx, s, rid_t, t_ev, ev_timer)
+                with jax.named_scope("repro.policy"):
+                    s = kernel.on_timer(ctx, s, rid_t, t_ev, ev_timer)
 
             # ------------------------------------------------ retry event
             ev_rtry = jnp.bool_(False)
@@ -1453,10 +1463,11 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             progress = ev_slot | ev_timer | ev_arr | ev_rtry
             s["ci"] = _bumps(s["ci"], {CI_NEXT: ev_arr,
                                        CI_ITERS: progress})
-            s = kernel.on_arrival(ctx, s, rid_na, t_na,
-                                  ev_arr | ev_rtry)
-
-            s = _fold_event(ctx, s)
+            with jax.named_scope("repro.policy"):
+                s = kernel.on_arrival(ctx, s, rid_na, t_na,
+                                      ev_arr | ev_rtry)
+            with jax.named_scope("repro.fold"):
+                s = _fold_event(ctx, s)
             s = dict(s)
             if trace:
                 # telemetry record: one fixed-width row per processed
@@ -1529,17 +1540,18 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             # plus, in exact mode, the batched overlay scatter into the
             # (L, N) per-request arrays (the only large-array write,
             # paid once per SEG events, not per event)
+            s = dict(s)
+            steps = s.pop("loop_steps")
             if not stream:
-                s = dict(s)
                 s["d_rid"] = jnp.full((L, SEG), N, jnp.int32)
             if trace:
                 from repro.telemetry.rail import TR_RF, TR_RI
-                s = dict(s)
                 s["tr_i"] = jnp.full((L, SEG, TR_RI), -1, jnp.int32)
                 s["tr_f"] = jnp.zeros((L, SEG, TR_RF), jnp.float64)
 
             def step(k, s):
-                ei, t_ev, t_arr = pick_events(s)
+                with jax.named_scope("repro.pick"):
+                    ei, t_ev, t_arr = pick_events(s)
                 return step_lanes(k, s, trace_ix, t_cold_l, t_evict_l,
                                   cap_mask, beta, nl, ei, t_ev, t_arr)
 
@@ -1552,7 +1564,10 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                     lane_iota, s["d_rid"]].set(s["d_comp"], mode="drop")
             if trace:
                 from repro.telemetry.rail import emit_flush
-                emit_flush(s["tr_i"], s["tr_f"])
+                with jax.named_scope("repro.flush"):
+                    emit_flush(s["tr_i"], s["tr_f"])
+            s = dict(s)
+            s["loop_steps"] = steps + SEG
             return s
 
         return lax.while_loop(cond, segment, s)
@@ -1566,7 +1581,8 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                stalled=ci[:, CI_STALL], n_events=ci[:, CI_ITERS],
                done=ci[:, CI_DONE],
                resp_sum=cf[:, CF_RSUM], slow_sum=cf[:, CF_SSUM],
-               max_response=cf[:, CF_RMAX], resp_hist=final["hist"])
+               max_response=cf[:, CF_RMAX], resp_hist=final["hist"],
+               loop_steps=final["loop_steps"])
     if tl_bins:
         out["tl_count"] = final["tl_cnt"]
         out["tl_resp_sum"] = final["tl_resp"]
@@ -1624,6 +1640,7 @@ def simulate_policy_jax(fn_id, arrival, exec_time, t_cold, t_evict, *,
                     kernel=kernel, n_fns=n_fns, capacity=capacity,
                     queue_cap=queue_cap, stream=stream, window=window,
                     tl_bins=tl_bins, tl_bucket=tl_bucket)
+    out.pop("loop_steps")
     return {k: jnp.squeeze(v, axis=0) for k, v in out.items()}
 
 
@@ -1720,7 +1737,8 @@ def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                evictions=out["evictions"],
                overflow=out["overflow"],
                stalled=out["stalled"],
-               n_events=out["n_events"])
+               n_events=out["n_events"],
+               loop_steps=out["loop_steps"])
     if tl_bins:
         res["tl_count"] = out["tl_count"]
         res["tl_resp_sum"] = out["tl_resp_sum"]

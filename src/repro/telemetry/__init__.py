@@ -1,5 +1,5 @@
 """repro.telemetry — in-loop event tracing, streaming metrics and
-profiling hooks.
+run provenance.
 
 Layers (all opt-in; disabled tracing lowers onto the unchanged event
 loops bitwise — see docs/observability.md):
@@ -13,8 +13,7 @@ loops bitwise — see docs/observability.md):
 - :mod:`repro.telemetry.metrics` — per-bin per-node time series
   (queue depth, warm occupancy, utilization, SLO attainment,
   goodput) with CSV and Prometheus exporters.
-- :mod:`repro.telemetry.profiling` — compile/run split, AOT phase
-  breakdown, run-provenance metadata.
+- :mod:`repro.telemetry.profiling` — run-provenance metadata.
 """
 from repro.telemetry.rail import (TraceKind, TraceSink, collect,
                                   merge_events)
@@ -23,15 +22,12 @@ from repro.telemetry.perfetto import (events_to_trace, save_trace,
                                       validate_trace)
 from repro.telemetry.metrics import (events_summary, timeline,
                                      timeline_to_csv, to_prometheus)
-from repro.telemetry.profiling import (PhaseTimer, compile_run_split,
-                                       jit_phase_breakdown,
-                                       provenance, spec_hash)
+from repro.telemetry.profiling import provenance, spec_hash
 
 __all__ = [
     "TraceKind", "TraceSink", "collect", "merge_events",
     "Span", "TraceRun", "assemble_spans",
     "events_to_trace", "save_trace", "validate_trace",
     "events_summary", "timeline", "timeline_to_csv", "to_prometheus",
-    "PhaseTimer", "compile_run_split", "jit_phase_breakdown",
     "provenance", "spec_hash",
 ]

@@ -1,19 +1,13 @@
-"""Profiling hooks: compile/run split, jit phase breakdown, and
-run-provenance metadata.
-
-These ride the registries that already exist — ``audit_jits()`` on
-the engines for the jit inventory, ``jit_cache_sizes()`` on the
-runners for cache state — and add wall-clock attribution so every
-benchmark row records *where* time went (trace / lower / compile /
-device execution) and *what* produced it (spec hash, backend,
-chunking)."""
+"""Run-provenance metadata: what produced a benchmark row (spec hash,
+backend, device, chunking, jit cache state). It rides the registries
+that already exist — ``jit_cache_sizes()`` on the runners for cache
+state. Where wall time goes is read from the runner's host spans in a
+`jax.profiler` trace (docs/observability.md, "Performance spans")."""
 from __future__ import annotations
 
 import hashlib
 import json
-import time
-from contextlib import contextmanager
-from typing import Callable, Dict, Optional
+from typing import Dict
 
 import jax
 
@@ -48,65 +42,3 @@ def provenance(spec=None, **extra) -> Dict[str, object]:
                                            False))
     out.update(extra)
     return out
-
-
-def compile_run_split(fn: Callable, *args, repeats: int = 3,
-                      **kwargs):
-    """Wall-clock compile vs steady-state split of a jitted call.
-
-    First call = compile + one run; best of ``repeats`` warm calls =
-    run. Returns ``(compile_s, run_s, result)`` where ``compile_s``
-    is the first-call wall time minus the warm time (floored at 0)."""
-    t0 = time.perf_counter()
-    res = jax.block_until_ready(fn(*args, **kwargs))
-    cold = time.perf_counter() - t0
-    best = float("inf")
-    for _ in range(max(1, repeats)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args, **kwargs))
-        best = min(best, time.perf_counter() - t0)
-    return max(cold - best, 0.0), best, res
-
-
-def jit_phase_breakdown(jitted, *args, **kwargs) -> Dict[str, float]:
-    """Per-phase wall clock of one jitted function via AOT stages:
-    abstract tracing, StableHLO lowering, backend compile, and one
-    device execution. Keys: ``trace_s, lower_s, compile_s, run_s``."""
-    t0 = time.perf_counter()
-    traced = jitted.trace(*args, **kwargs)
-    t1 = time.perf_counter()
-    lowered = traced.lower()
-    t2 = time.perf_counter()
-    compiled = lowered.compile()
-    t3 = time.perf_counter()
-    jax.block_until_ready(compiled(*args, **kwargs))
-    t4 = time.perf_counter()
-    return dict(trace_s=t1 - t0, lower_s=t2 - t1, compile_s=t3 - t2,
-                run_s=t4 - t3)
-
-
-class PhaseTimer:
-    """Named wall-clock phase accumulator.
-
-    >>> pt = PhaseTimer()
-    >>> with pt.phase("lower"):
-    ...     do_work()
-    >>> pt.report()  # {'lower': 0.12}
-    """
-
-    def __init__(self):
-        self.acc: Dict[str, float] = {}
-
-    @contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.acc[name] = (self.acc.get(name, 0.0)
-                              + time.perf_counter() - t0)
-
-    def report(self, ndigits: Optional[int] = 6) -> Dict[str, float]:
-        if ndigits is None:
-            return dict(self.acc)
-        return {k: round(v, ndigits) for k, v in self.acc.items()}

@@ -288,11 +288,7 @@ def test_span_assembly_from_raw_events():
 
 # ---------------------------------------------- profiling hooks
 def test_profiling_hooks():
-    import jax.numpy as jnp
-
-    from repro.telemetry import (PhaseTimer, compile_run_split,
-                                 jit_phase_breakdown, provenance,
-                                 spec_hash)
+    from repro.telemetry import provenance, spec_hash
     spec = ExperimentSpec(**BASE).validate()
     prov = provenance(spec)
     for k in ("backend", "jax_version", "x64", "spec_hash",
@@ -300,21 +296,3 @@ def test_profiling_hooks():
         assert k in prov
     assert prov["spec_hash"] == spec_hash(spec)
     assert prov["trace_events"] is False
-
-    import jax
-    f = jax.jit(lambda x: x * 2 + 1)
-    c, r, out = compile_run_split(f, jnp.arange(8.0))
-    assert c >= 0 and r >= 0
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.arange(8.0) * 2 + 1)
-    ph = jit_phase_breakdown(f, jnp.arange(8.0))
-    assert set(ph) >= {"trace_s", "lower_s", "compile_s", "run_s"}
-
-    pt = PhaseTimer()
-    with pt.phase("a"):
-        pass
-    with pt.phase("b"):
-        pass
-    rep = pt.report()
-    assert set(rep) == {"a", "b"} and all(v >= 0
-                                          for v in rep.values())

@@ -794,20 +794,38 @@ def est_means(ctx, s):
     return ctx.est_means(s)
 
 
+def _fn_histogram(ctx, s, mask):
+    """Per-function count of the slots in ``mask``, as an (F,) int32.
+
+    A dense one-hot compare over the slot axis, summed, and not a
+    scatter-add: XLA:TPU serializes a scatter over its L x C updates
+    under the lane vmap, and the ESFF kernels count on every event
+    step. Integer counts, so a scatter-add gives the same bits. The
+    (C, F) compare grows with F; a caller that needs one function's
+    count takes `fn_count` instead, which stays O(C)."""
+    fns = jnp.arange(ctx.F, dtype=jnp.int32)
+    hit = (s["slot_fn"][:, None] == fns[None, :]) & mask[:, None]
+    return hit.sum(0, dtype=jnp.int32)
+
+
 def k_counts(ctx, s):
     """|K^j| — slots assigned to each function, any state."""
-    return jnp.zeros((ctx.F,), jnp.int32).at[
-        jnp.where(s["slot_fn"] >= 0, s["slot_fn"], jnp.int32(ctx.F))
-    ].add(jnp.int32(1), mode="drop")
+    return _fn_histogram(ctx, s, s["slot_fn"] >= 0)
 
 
 def cold_counts(ctx, s):
     """Slots currently warming up (state COLD) per function."""
-    warming = s["slot_state"] == COLD
-    return jnp.zeros((ctx.F,), jnp.int32).at[
-        jnp.where((s["slot_fn"] >= 0) & warming, s["slot_fn"],
-                  jnp.int32(ctx.F))
-    ].add(jnp.int32(1), mode="drop")
+    return _fn_histogram(ctx, s, (s["slot_fn"] >= 0)
+                         & (s["slot_state"] == COLD))
+
+
+def fn_count(s, fn, cold=False):
+    """Entry ``fn`` of `k_counts` (``cold``: of `cold_counts`) for one
+    function ``fn`` in [0, F): a masked sum over the (C,) slot rail."""
+    hit = s["slot_fn"] == fn
+    if cold:
+        hit = hit & (s["slot_state"] == COLD)
+    return hit.sum(dtype=jnp.int32)
 
 
 def idle_own(ctx, s, fn):

@@ -48,13 +48,13 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.jax_engine import (BIG, COLD, IDLE, EngineCtx,
-                                   PolicyKernel, _bump, _gidx, _put,
-                                   arm_timer,
+from repro.core.jax_engine import (BIG, COLD, IDLE, PolicyKernel,
+                                   _bump, _gidx, _put, arm_timer,
                                    cold_counts, dispatch, est_means,
-                                   k_counts, lex_argmin, pick_idle_own,
-                                   q_consume_direct, q_head, q_pop,
-                                   q_push, rearm_timer, start_cold)
+                                   fn_count, k_counts, lex_argmin,
+                                   pick_idle_own, q_consume_direct,
+                                   q_head, q_pop, q_push, rearm_timer,
+                                   start_cold)
 
 
 class ESFFKernel(PolicyKernel):
@@ -67,18 +67,14 @@ class ESFFKernel(PolicyKernel):
         self.cold_aware = cold_aware
         self.default_beta = default_beta
 
-    def _drain_terms(self, ctx: EngineCtx, s):
-        """means, |K|, and the cold-instance correction of Eq. 6/7."""
-        means = est_means(ctx, s)
-        K = k_counts(ctx, s)
-        coldK = (cold_counts(ctx, s).astype(jnp.float64)
-                 if self.cold_aware else None)
-        return means, K, coldK
-
     # ------------------------------------------------- FCP (Algorithm 2)
     def on_arrival(self, ctx, s, rid, t, on):
         j = ctx.fn_at(rid)
-        means, K, coldK = self._drain_terms(ctx, s)
+        # only function j's counts: O(C) masked sums, no (F,) histogram
+        means = est_means(ctx, s)
+        Kj = fn_count(s, j)
+        coldKj = (fn_count(s, j, cold=True).astype(jnp.float64)
+                  if self.cold_aware else None)
         has_own, own_slot = pick_idle_own(ctx, s, j)
         direct = on & has_own & (s["q_len"][j] == 0)
         s = dispatch(ctx, s, own_slot, rid, t, direct)
@@ -86,9 +82,9 @@ class ESFFKernel(PolicyKernel):
         queued = on & ~direct
 
         empty = (s["slot_fn"] < 0) & ctx.cap_mask
-        n_e = s["q_len"][j] + 1.0 - ctx.t_cold[j] * K[j] / means[j]
+        n_e = s["q_len"][j] + 1.0 - ctx.t_cold[j] * Kj / means[j]
         if self.cold_aware:
-            n_e = n_e - coldK[j]
+            n_e = n_e - coldKj
         s = start_cold(ctx, s, jnp.argmax(empty), j, t, -1,
                        queued & empty.any() & (n_e > 0))
 
@@ -96,9 +92,9 @@ class ESFFKernel(PolicyKernel):
                 & (s["slot_fn"] != j) & ctx.cap_mask)
         sf = jnp.where(s["slot_fn"] >= 0, s["slot_fn"], 0)
         n_e2 = (s["q_len"][j] + 1.0
-                - (ctx.t_cold[j] + ctx.t_evict[sf]) * K[j] / means[j])
+                - (ctx.t_cold[j] + ctx.t_evict[sf]) * Kj / means[j])
         if self.cold_aware:
-            n_e2 = n_e2 - coldK[j]
+            n_e2 = n_e2 - coldKj
         elig = idle & (n_e2 > 0)
         # Eq. 8 victim: argmax t̄_e (ESFF) or LRU (ESFF-H), ties toward
         # the earliest-created instance
@@ -120,8 +116,8 @@ class ESFFKernel(PolicyKernel):
     def on_exec_done(self, ctx, s, slot, rid, t, on):
         j = s["slot_fn"][slot]
         jc = jnp.clip(j, 0, ctx.F - 1)
-        means, K, coldK = self._drain_terms(ctx, s)
-        K = K.astype(jnp.float64)
+        means = est_means(ctx, s)
+        K = k_counts(ctx, s).astype(jnp.float64)
         nw = s["q_len"].astype(jnp.float64)
         # Eq. (9)
         w_own = jnp.where(
@@ -132,7 +128,7 @@ class ESFFKernel(PolicyKernel):
         # Eq. (7) swapped + Eq. (10) with beta hysteresis
         n_e = nw + 1.0 - (ctx.t_cold + ctx.t_evict[jc]) * K / means
         if self.cold_aware:
-            n_e = n_e - coldK
+            n_e = n_e - cold_counts(ctx, s).astype(jnp.float64)
         w = (means + ctx.beta * (ctx.t_cold + ctx.t_evict) * (K + 1.0)
              / jnp.maximum(n_e, 1e-30))
         idx = jnp.arange(ctx.F)

@@ -11,6 +11,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -66,24 +68,72 @@ def _resil_ops(shape):
                 rs_key=shape((1, N), jnp.int32))
 
 
+def _computations(hlo):
+    """``{name: [instruction lines]}`` of an HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if name is None and head:
+            name, comps[head.group(1)] = head.group(1), []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _loop_scatters(hlo):
+    """Scatter instructions in the computations a ``while`` body runs,
+    nested fusions, calls and loops included."""
+    comps = _computations(hlo)
+    callee = re.compile(r"(?:body|condition|to_apply|calls|"
+                        r"branch_computations|called_computations)="
+                        r"\{?(%[\w.\-]+(?:, *%[\w.\-]+)*)")
+    todo = [b for lines in comps.values() for line in lines
+            for b in re.findall(r"body=%([\w.\-]+)", line)]
+    assert todo, "no while loop in the compiled module"
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            for group in callee.findall(line):
+                todo.extend(x.strip().lstrip("%")
+                            for x in group.split(","))
+    return [line.strip() for c in seen for line in comps[c]
+            if re.search(r"\bscatter\(", line)]
+
+
 @pytest.mark.parametrize("case", [
-    # the fig. 5 grid in one call: 6 policies x 7 capacities, streamed
-    dict(L=42, stream=True),
+    # the fig. 5 grid in one call: 6 policies x 7 capacities, streamed;
+    # ESFF's per-function slot counts are dense compare-and-sums, so
+    # the event step holds no (serialized) scatter
+    dict(L=42, stream=True, no_loop_scatter=True),
     # per-request records (`keep_per_request=True`)
     dict(L=7, stream=False, keep_responses=True),
     # retries re-link the per-request `nxt` rail inside the event body
     dict(L=7, stream=True, resil=RESIL_SHED_OLDEST),
-], ids=["stream", "exact_keep_responses", "resil_shed_oldest"])
+    # ESFF-H adds the cold-slot counts to the same step
+    dict(L=7, stream=True, policy="esff_h", no_loop_scatter=True),
+], ids=["stream", "exact_keep_responses", "resil_shed_oldest",
+        "esff_h_stream"])
 def test_single_node_loop_compiles(shape, case):
     from repro.core.jax_engine import _sweep_metrics
     from repro.core.jax_policies import KERNELS
     case = dict(case)
     L = case.pop("L")
+    policy = case.pop("policy", "esff")
+    no_loop_scatter = case.pop("no_loop_scatter", False)
     extra = _resil_ops(shape) if "resil" in case else {}
     compiled = _sweep_metrics.lower(
-        *_trace_args(shape, L, (32,)), **extra, kernel=KERNELS["esff"],
+        *_trace_args(shape, L, (32,)), **extra, kernel=KERNELS[policy],
         n_fns=F, capacity=32, queue_cap=N, **case).compile()
-    assert compiled.as_text()
+    hlo = compiled.as_text()
+    assert hlo
+    if no_loop_scatter:
+        assert _loop_scatters(hlo) == []
 
 
 def test_cluster_loop_compiles(shape):

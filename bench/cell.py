@@ -3,22 +3,39 @@ mix and the workload, and the units of work they define.
 
 A unit is one `repro.api.run_experiment` call on a set of request
 streams, followed by `ResultSet.check()`. The traffic mix fixes a pool
-of units, whose streams come from `bench.traffic.azure` with the seed
-``[pool seed, unit, stream]``; a run's seed orders the pool. Every run
-thus does the same work, in another order. This is the only module
+of units, whose streams come from the configuration's sampler with the
+seed ``[pool seed, unit, stream]``; a run's seed orders the pool. Every
+run thus does the same work, in another order. This is the only module
 that builds the program's inputs.
+
+A configuration brings a mechanism of its own as new files, through
+four optional keys:
+
+* ``trace.sampler``: the module of `bench.traffic` whose
+  ``stream(config, seed)`` makes a stream (default ``azure``); extra
+  columns pass through to the program;
+* ``spec``: options given to `repro.api.ExperimentSpec` as they are,
+  after the fixed arguments; a workload's ``spec`` overrides the
+  configuration's. An option the program does not know raises there;
+* ``reference``: the module of `bench.reference` whose
+  ``lane_stats(cell, streams, lane, r)`` is the plain reference
+  (default ``node``, or ``cluster`` with ``n_nodes``);
+* ``check_stats``: `ResultSet` metrics compared beside
+  `bench.check.GAP_STATS`, which both sides must report.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 from dataclasses import dataclass
 
 import numpy as np
 
-from bench.traffic.azure import sample
-
 BENCH = os.path.dirname(os.path.abspath(__file__))
+# the `ExperimentSpec` arguments `Cell.spec` always sets itself
+FIXED_SPEC = frozenset(("traces", "policies", "capacities", "queue_cap",
+                        "stream", "prior", "deadlines", "cluster"))
 
 
 def load_json(kind: str, name: str, root: str = BENCH) -> dict:
@@ -60,6 +77,12 @@ class Cell:
     traffic: dict
     workload: dict
 
+    def __post_init__(self):
+        fixed = sorted(FIXED_SPEC & set(self.options))
+        if fixed:
+            raise ValueError(f"cell {self.name}: spec option(s) {fixed} "
+                             f"are the harness's own arguments")
+
     @staticmethod
     def load(name: str, root: str = BENCH) -> "Cell":
         """The cell ``name`` from the files under ``root``."""
@@ -70,17 +93,9 @@ class Cell:
     # ------------------------------------------------------------ traffic
     def stream(self, unit: int, k: int) -> dict:
         """Request stream ``k`` of pool unit ``unit``."""
-        c = self.config["trace"]
-        return sample(self.config["n_functions"], self.config["n_requests"],
-                      utilization=c["utilization"],
-                      capacity_ref=c["capacity_ref"], zipf_a=c["zipf_a"],
-                      exec_median=c["exec_median_s"],
-                      exec_sigma=c["exec_sigma"],
-                      jitter_sigma=c["jitter_sigma"],
-                      cold_range=tuple(c["cold_range_s"]),
-                      burst_frac=c["burst_frac"],
-                      diurnal_amp=c["diurnal_amp"],
-                      seed=[self.traffic["pool_seed"], int(unit), int(k)])
+        sampler = self.config["trace"].get("sampler", "azure")
+        return importlib.import_module(f"bench.traffic.{sampler}").stream(
+            self.config, [self.traffic["pool_seed"], int(unit), int(k)])
 
     def pool(self) -> list:
         """The streams of every unit of the pool."""
@@ -110,6 +125,23 @@ class Cell:
                     churn=None if churn is None else churn_windows(churn, K))
 
     @property
+    def options(self) -> dict:
+        """The program's options: the configuration's ``spec``, updated
+        by the workload's."""
+        return {**self.config.get("spec", {}), **self.workload.get("spec", {})}
+
+    @property
+    def reference(self) -> str:
+        """The module of `bench.reference` that simulates this cell."""
+        return self.config.get("reference",
+                               "cluster" if self.clustered else "node")
+
+    @property
+    def check_stats(self) -> tuple:
+        """The configuration's own statistics to compare."""
+        return tuple(self.config.get("check_stats", ()))
+
+    @property
     def capacities(self) -> tuple:
         if self.clustered:
             return (self.config["n_nodes"] * self.config["node_capacity"],)
@@ -120,8 +152,9 @@ class Cell:
         """Requests x lanes: the simulated work of one unit."""
         return self.config["n_requests"] * len(self.lanes)
 
-    def spec(self, streams: list):
-        """The unit's `repro.api.ExperimentSpec` over ``streams``."""
+    def spec(self, streams=()):
+        """The unit's `repro.api.ExperimentSpec` over ``streams``; with
+        none, it only checks the options."""
         from repro.api import ArrayTrace, ClusterSpec, ExperimentSpec
         traces = [ArrayTrace.make(a, f"stream{k}")
                   for k, a in enumerate(streams)]
@@ -139,7 +172,7 @@ class Cell:
                 node_capacity=(ck["node_capacity"],) * K,
                 net_delay=ck["net_delay"], seed=ck["seed"],
                 churn=None if ck["churn"] is None else tuple(ck["churn"]))]
-        return ExperimentSpec(**kw)
+        return ExperimentSpec(**kw, **self.options)
 
     @property
     def lanes(self) -> list:
@@ -150,8 +183,9 @@ class Cell:
                 for c in self.capacities]
 
 
-def lane_values(rs, policy: str, capacity: int, k: int) -> dict:
-    """The statistics of one lane of a unit's `ResultSet`."""
+def lane_values(rs, policy: str, capacity: int, k: int, stats=()) -> dict:
+    """The statistics of one lane of a unit's `ResultSet`, with those of
+    ``stats`` that it reports."""
     which = dict(policy=policy, capacity=capacity,
                  trace=rs.coords["trace"][k])
     if "cluster" in rs.coords:
@@ -163,4 +197,7 @@ def lane_values(rs, policy: str, capacity: int, k: int) -> dict:
     if "deadline_miss" in rs.data:
         out["deadline_miss"] = int(np.asarray(
             cell.value("deadline_miss")).sum())
+    for m in stats:
+        if m in rs.data:
+            out[m] = np.asarray(cell.value(m)).sum().item()
     return out

@@ -33,7 +33,8 @@ def readings(cell, seeds: list):
                                             r=node.float32),
                             check.reference(cell, pool[u], lane))
                            for lane in cell.lanes]
-        yield seed, check.compare([r for u in units for r in rows[u]])
+        yield seed, check.compare([r for u in units for r in rows[u]],
+                                  cell.check_stats)
 
 
 def main(argv=None):

@@ -222,3 +222,13 @@ def simulate(arrays, policy, *, n_nodes, node_capacity, router, net_delay,
             1 for q in requests if q.completion >= 0
             and r(q.completion - base[q.req_id]) > dl)
     return out
+
+
+def lane_stats(cell, streams, lane, r=float):
+    """The statistics of ``lane = (policy, capacity, k)`` of a
+    `bench.cell.Cell`'s unit over ``streams``: the entry point of
+    `bench.check.reference`."""
+    policy, _, k = lane
+    return simulate(streams[k], policy, prior=cell.config["prior_s"], r=r,
+                    deadline=cell.traffic.get("deadline_s"),
+                    **cell.cluster_kw())

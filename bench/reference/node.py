@@ -583,3 +583,12 @@ def simulate(arrays, policy, capacity, *, prior=0.1, r=float):
     out.update(cold_starts=server.cold_starts, evictions=server.evictions,
                cold_time=server.cold_time)
     return out
+
+
+def lane_stats(cell, streams, lane, r=float):
+    """The statistics of ``lane = (policy, capacity, k)`` of a
+    `bench.cell.Cell`'s unit over ``streams``: the entry point of
+    `bench.check.reference`."""
+    policy, capacity, k = lane
+    return simulate(streams[k], policy, capacity,
+                    prior=cell.config["prior_s"], r=r)

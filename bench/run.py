@@ -122,9 +122,10 @@ def correctness(cell, pool, results, seed):
     for i in check.draw(len(units), cell.workload["check_units"], seed):
         u = units[i]
         for lane in cell.lanes:
-            rows.append((lane, lane_values(results[u], *lane),
+            rows.append((lane,
+                         lane_values(results[u], *lane, cell.check_stats),
                          check.reference(cell, pool[u], lane)))
-    return check.compare(rows)
+    return check.compare(rows, cell.check_stats)
 
 
 def main(argv=None):
@@ -145,6 +146,7 @@ def main(argv=None):
     cached = len(os.listdir(CACHE_DIR))
     from repro.api import run_experiment
 
+    cell.spec()  # an option the program lacks fails before any stream
     pool, order = cell.pool(), cell.order(args.seed)
     t_warm = time.perf_counter()
     _, ok = run_unit(run_experiment, cell, pool[order[0]])

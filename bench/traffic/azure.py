@@ -13,6 +13,9 @@ For the same parameters it returns the same arrays bit for bit.
 * arrivals: per-minute counts from a diurnal profile times a log-normal
   burst process, uniform within each minute;
 * cold-start and eviction times ~ U[``cold_range``] per function.
+
+`stream` is the entry point `bench.cell.Cell.stream` calls for a
+configuration whose ``trace`` block names no other sampler.
 """
 from __future__ import annotations
 
@@ -74,3 +77,17 @@ def sample(n_functions, n_requests, *, utilization, capacity_ref, zipf_a,
                 exec_time=execs[order].astype(np.float64),
                 cold_start=np.asarray(cold, np.float64),
                 evict=np.asarray(evict, np.float64))
+
+
+def stream(config: dict, seed) -> dict:
+    """The stream of ``config``: its ``trace`` block holds `sample`'s
+    parameters, with times in seconds."""
+    c = config["trace"]
+    return sample(config["n_functions"], config["n_requests"],
+                  utilization=c["utilization"],
+                  capacity_ref=c["capacity_ref"], zipf_a=c["zipf_a"],
+                  exec_median=c["exec_median_s"], exec_sigma=c["exec_sigma"],
+                  jitter_sigma=c["jitter_sigma"],
+                  cold_range=tuple(c["cold_range_s"]),
+                  burst_frac=c["burst_frac"], diurnal_amp=c["diurnal_amp"],
+                  seed=seed)

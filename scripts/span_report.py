@@ -4,6 +4,8 @@ scope. Needs a TPU; run it from the root of the checkout:
 
     python3 scripts/span_report.py --workload paper_edge.fig5 \
         --scopes-n 300 --out span_report.json
+    python3 scripts/span_report.py --workload keepalive_node.ram_grid \
+        --scopes-policy faascache --out span_report.json
 
 Without ``--out`` the whole report goes to standard output.
 
@@ -12,10 +14,14 @@ warm without the profiler, then under the benchmark's host-mode
 profiler (`bench.trace.capture`), then again without it; it reduces the
 ``repro.*`` spans and their stats (docs/observability.md, "Performance
 spans") and sets them beside what `bench.trace` reads from the same
-trace. Part 2 traces one esff launch of the cell's capacities over the
-first ``--scopes-n`` requests of that stream in the profiler's default
-mode, which records every device op, and sums the device time of the
-ops under each of the event step's named scopes.
+trace, with the launches' counters summed (``loop_steps``, and on a node
+of memory ``multi_victim_starts`` and ``mem_waits``). Part 2 traces one
+launch of ``--scopes-policy`` (esff by default) over the cell's
+capacities and options and the first ``--scopes-n`` requests of that
+stream in the profiler's default mode, which records every device op,
+and sums the device time of the ops under each of the event step's
+named scopes; ``repro.admit`` (a memory-mode cold start) is counted
+apart from the ``repro.policy`` scope it sits in.
 """
 import argparse
 import glob
@@ -31,7 +37,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 SERIAL = ("repro.lower", "repro.assemble", "repro.check")
-SCOPES = ("repro.pick", "repro.policy", "repro.fold", "repro.flush")
+# the first that an op's scope path names: repro.admit nests in
+# repro.policy
+SCOPES = ("repro.pick", "repro.admit", "repro.policy", "repro.fold",
+          "repro.flush")
+COUNTERS = ("loop_steps", "multi_victim_starts", "mem_waits")
 
 
 def union_s(intervals) -> float:
@@ -82,6 +92,8 @@ def reduce_unit(pd) -> dict:
         "spans": {n: {"count": len(v), "union_s": union_s(
             (s, e) for s, e, _ in v)} for n, v in sorted(sp.items())},
         "dispatch": [st for _, _, st in sp.get("repro.dispatch", [])],
+        "counters": {k: sum(st.get(k, 0) for _, _, st in fetch)
+                     for k in COUNTERS},
         "fetch": [dict(st, start_s=s - lo, end_s=e - lo)
                   for s, e, st in fetch],
         "device_us_per_step.single": 1e6 * fetch_s / steps,
@@ -112,18 +124,18 @@ def scope_of(name: str, op_names: dict) -> str:
     return next((s for s in SCOPES if s in text), "other")
 
 
-def scope_split(cell, stream, n: int) -> dict:
-    """Device time per named scope of one esff launch over the first
-    ``n`` requests of ``stream``, in a default-mode trace."""
+def scope_split(cell, stream, n: int, policy: str = "esff") -> dict:
+    """Device time per named scope of one ``policy`` launch over the
+    first ``n`` requests of ``stream``, in a default-mode trace."""
     import jax
     from repro.api import ArrayTrace, ExperimentSpec, run_experiment
     from repro.core import jax_engine
     head = {k: (v[:n] if len(v) == len(stream["fn_id"]) else v)
             for k, v in stream.items()}
     spec = ExperimentSpec(traces=[ArrayTrace.make(head, "head")],
-                          policies=("esff",), capacities=cell.capacities,
+                          policies=(policy,), capacities=cell.capacities,
                           queue_cap=n, stream=True,
-                          prior=cell.config["prior_s"])
+                          prior=cell.config["prior_s"], **cell.options)
     sweep, calls = jax_engine._sweep_metrics, []
 
     def recorded(*a, **kw):
@@ -167,7 +179,8 @@ def scope_split(cell, stream, n: int) -> dict:
                 by_op[(s, opcode)] = by_op.get((s, opcode), 0.0) + us
     steps, = rs.meta["loop_steps"]
     top = sorted(by_op.items(), key=lambda x: -x[1])[:15]
-    return {"n_requests": n, "lanes": len(cell.capacities),
+    return {"policy": policy, "n_requests": n,
+            "lanes": len(cell.capacities),
             "loop_steps": steps, "op_events": ops,
             "module_us": module_us, "device_us": tot,
             "device_us_per_step": {k: v / steps for k, v in tot.items()},
@@ -179,6 +192,7 @@ def main(argv=None):
     ap.add_argument("--workload", default="paper_edge.fig5")
     ap.add_argument("--unit", type=int, default=0)
     ap.add_argument("--scopes-n", type=int, default=300)
+    ap.add_argument("--scopes-policy", default="esff")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
 
@@ -205,7 +219,8 @@ def main(argv=None):
     report.update(reduce_unit(profile(tmp)))
     shutil.rmtree(tmp, ignore_errors=True)
     if args.scopes_n:
-        report["scopes"] = scope_split(cell, streams[0], args.scopes_n)
+        report["scopes"] = scope_split(cell, streams[0], args.scopes_n,
+                                       args.scopes_policy)
     if args.out is None:
         print(json.dumps(report, indent=1))
         return

@@ -44,6 +44,11 @@ _BETA_DEFAULT = "default"
 # grouped grid is bitwise the stacked one (gated in tests/test_api.py).
 ROW_SPLIT_ELEMS = 1 << 16
 
+# counters every launch reports beside ``loop_steps``, summed over its
+# lanes: in `ResultSet.meta` (one entry per chunk) and the stats of the
+# ``repro.fetch`` span. Nonzero only on a node of memory (``slot_mb``).
+LAUNCH_COUNTERS = ("multi_victim_starts", "mem_waits")
+
 
 def _unique_labels(labels):
     """Disambiguate repeated source labels positionally (``#k`` suffix)
@@ -75,6 +80,9 @@ def _lower_grid(spec: ExperimentSpec):
     stacked = {k: np.stack([np.asarray(a[k]) for a in arrs])
                for k in ("fn_id", "arrival", "exec_time", "cold_start",
                          "evict")}
+    mem = spec.memory_ops(arrs, sources)
+    if mem is not None:
+        stacked["mem_mb"] = mem
     return sources, stacked, F, N
 
 
@@ -146,8 +154,14 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
             eff, rs_nfail, rs_tmo, rs_key, resil = rs
             stacked = dict(stacked, exec_time=eff)
         T = len(sources)
-        C = max(spec.capacities)
-        masks = np.stack([np.arange(C) < c for c in spec.capacities])
+        # slots per lane: the capacity, or on a node of memory the most
+        # instances its memory holds
+        slots = list(spec.capacities)
+        if spec.slot_mb is not None:
+            from repro.core.server import MIN_MEM_MB
+            slots = [c * spec.slot_mb // MIN_MEM_MB for c in slots]
+        C = max(slots)
+        masks = np.stack([np.arange(C) < c for c in slots])
         chunk = resolve_lane_chunk(spec.lane_chunk)
         row_split = T > 1 and T * N > ROW_SPLIT_ELEMS
         plan, K, B = _chunk_plan(spec, T, chunk, row_split)
@@ -197,6 +211,8 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
         # policy: betas=None resolves per kernel at chunk build time)
         tix_col = np.repeat(np.arange(T, dtype=np.int32), K * B)
         mask_col = np.tile(np.repeat(masks, B, axis=0), (T, 1))
+        node_col = (None if spec.slot_mb is None else np.tile(np.repeat(
+            np.asarray(spec.capacities, np.int32) * spec.slot_mb, B), T))
 
         def beta_col(policy: str) -> np.ndarray:
             bs = np.asarray(
@@ -220,11 +236,15 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
             tix_l = jnp.zeros((hi - lo,), jnp.int32)
         mask_l = jnp.asarray(mask_col[lo:hi])
         beta_l = jnp.asarray(beta_cols[policy][lo:hi])
+        node_l = (None if node_col is None
+                  else jnp.asarray(node_col[lo:hi]))
         if multi_dev:
             dev = devs[di]
             tix_l = jax.device_put(tix_l, dev)
             mask_l = jax.device_put(mask_l, dev)
             beta_l = jax.device_put(beta_l, dev)
+            if node_l is not None:
+                node_l = jax.device_put(node_l, dev)
         with TraceAnnotation("repro.dispatch") as span:
             if TraceAnnotation.is_enabled():
                 span.set_metadata(chunk=ci, policy=policy, lanes=hi - lo)
@@ -235,6 +255,7 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
                 deadlines=dl_op,
                 rs_nfail=sh.get("rs_nfail"), rs_tmo=sh.get("rs_tmo"),
                 rs_key=sh.get("rs_key"), resil=resil,
+                mem_mb=sh.get("mem_mb"), node_mb=node_l,
                 kernel=kernels[policy], n_fns=F, capacity=C,
                 queue_cap=spec.queue_cap, stream=spec.stream,
                 window=spec.window, tl_bins=spec.tl_bins,
@@ -245,23 +266,27 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
                          for d in v.devices()})
         with TraceAnnotation("repro.fetch") as span:
             out = jax.device_get(out)
-            steps = int(out.pop("loop_steps"))
+            # per-launch counters: loop steps, and on a node of memory
+            # the cold starts with several victims and those deferred
+            ctr = dict(loop_steps=int(out.pop("loop_steps")))
+            for k in LAUNCH_COUNTERS:
+                ctr[k] = int(np.sum(out.pop(k, 0), dtype=np.int64))
             if TraceAnnotation.is_enabled():
                 span.set_metadata(
-                    chunk=ci, lanes=hi - lo, loop_steps=steps,
+                    chunk=ci, lanes=hi - lo, **ctr,
                     lane_events=int(np.sum(out["n_events"],
                                            dtype=np.int64)))
-        return ci, out, placed, steps
+        return ci, out, placed, ctr
 
     if spec.trace_events:
         # one collect scope per chunk: device_get inside run_chunk
         # blocks, so every ordered flush lands before the scope closes
         from repro.telemetry import rail
-        outs, placed, steps = {}, {}, {}
+        outs, placed, ctrs = {}, {}, {}
         lane_events: Dict[tuple, dict] = {}
         for ci in mine:
             with rail.collect() as sink:
-                _, outs[ci], placed[ci], steps[ci] = run_chunk(ci)
+                _, outs[ci], placed[ci], ctrs[ci] = run_chunk(ci)
             pi, lo, hi = plan[ci]
             for j in range(hi - lo):
                 lane_events[(pi, lo + j)] = sink.lane_events(j)
@@ -275,7 +300,7 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
             done = list(tp.map(run_chunk, mine))
         outs = {ci: out for ci, out, _, _ in done}
         placed = {ci: ids for ci, _, ids, _ in done}
-        steps = {ci: n for ci, _, _, n in done}
+        ctrs = {ci: n for ci, _, _, n in done}
 
     # ------------------------------------------------------- assembly
     with TraceAnnotation("repro.assemble"):
@@ -324,7 +349,10 @@ def _run_single_node(spec: ExperimentSpec) -> ResultSet:
                                 else list(spec.deadlines))),
                     n_devices=len(devs), backend=jax.default_backend(),
                     chunk_devices=[placed[ci] for ci in mine],
-                    loop_steps=[steps[ci] for ci in mine],
+                    loop_steps=[ctrs[ci]["loop_steps"] for ci in mine],
+                    slot_mb=spec.slot_mb,
+                    **{k: [ctrs[ci][k] for ci in mine]
+                       for k in LAUNCH_COUNTERS},
                     resilience=spec.resilience_meta(),
                     seeds=(list(spec.seeds) if spec.seeds is not None
                            else None),

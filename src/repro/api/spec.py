@@ -24,6 +24,9 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import numpy as np
 
 TRACE_COLUMNS = ("fn_id", "arrival", "exec_time", "cold_start", "evict")
+# optional per-function column: the footprint in MB that a node of memory
+# (`ExperimentSpec.slot_mb`) charges each instance
+MEM_COLUMN = "mem_mb"
 
 # engine defaults mirrored here so a spec is self-describing
 DEFAULT_POLICIES = ("esff", "esff_h", "sff", "openwhisk", "faascache",
@@ -95,7 +98,8 @@ class TraceSource:
 
 def validate_trace_arrays(a: dict, where: str = "trace"
                           ) -> Dict[str, np.ndarray]:
-    """Check/normalise a columnar trace dict (`TRACE_COLUMNS` layout)."""
+    """Check/normalise a columnar trace dict (`TRACE_COLUMNS` layout,
+    plus the optional integer `MEM_COLUMN`, kept as int32)."""
     missing = [k for k in TRACE_COLUMNS if k not in a]
     if missing:
         raise ValueError(f"{where}: missing trace column(s) {missing}; "
@@ -115,6 +119,14 @@ def validate_trace_arrays(a: dict, where: str = "trace"
     if n and out["fn_id"].max(initial=0) >= len(out["cold_start"]):
         raise ValueError(f"{where}: fn_id exceeds catalogue size "
                          f"{len(out['cold_start'])}")
+    if MEM_COLUMN in a:
+        raw = np.asarray(a[MEM_COLUMN])
+        mem = np.ascontiguousarray(raw, np.int32)
+        if mem.shape != out["cold_start"].shape or not np.array_equal(
+                mem, raw):
+            raise ValueError(f"{where}: {MEM_COLUMN} needs one whole "
+                             "number of MB per function")
+        out[MEM_COLUMN] = mem
     return out
 
 
@@ -168,7 +180,8 @@ class NpzTrace(TraceSource):
                 f"NpzTrace: no npz at {self.path!r} (see "
                 "docs/azure_trace.md for producing one)")
         with np.load(self.path) as z:
-            return {k: z[k] for k in TRACE_COLUMNS}
+            return {k: z[k] for k in TRACE_COLUMNS + (MEM_COLUMN,)
+                    if k in z.files}
 
 
 @dataclass(frozen=True)
@@ -222,6 +235,8 @@ class HeadTrace(TraceSource):
                                            "exec_time")}
         out["cold_start"] = a["cold_start"]
         out["evict"] = a["evict"]
+        if MEM_COLUMN in a:
+            out[MEM_COLUMN] = a[MEM_COLUMN]
         return out
 
     def with_seed(self, seed: int) -> "HeadTrace":
@@ -326,6 +341,19 @@ class ExperimentSpec:
     Traced runs execute lane chunks serially on the default device
     (``devices`` must be None or 1, ``host_shard`` (0, 1));
     ``trace_events=False`` lowers onto the unchanged loops bitwise.
+
+    Memory (docs/api.md): ``slot_mb`` makes the node's resource memory,
+    as in OpenWhisk's container pool — a lane of capacity ``c`` is a
+    node of ``c * slot_mb`` MB of user memory, and every trace carries a
+    per-function integer ``mem_mb`` column (each value in [128, the
+    smallest lane's memory]). An instance holds its footprint from its
+    cold start until its eviction; a cold start evicts idle instances in
+    the policy's victim order until it fits, or waits. Only
+    ``openwhisk``, ``sff`` and ``faascache`` (whose GREEDY-DUAL priority
+    then divides by the footprint) support it, and only on a single
+    node. The ResultSet gains ``evict_mb`` per cell, and ``meta`` the
+    per-launch ``multi_victim_starts`` and ``mem_waits``. ``None``
+    keeps the uniform slots and lowers onto the unchanged loops.
     """
 
     traces: Sequence = ()
@@ -352,6 +380,7 @@ class ExperimentSpec:
     host_shard: Tuple[int, int] = (0, 1)
     cluster: Optional[Sequence] = None
     trace_events: bool = False
+    slot_mb: Optional[int] = None
     meta: dict = field(default_factory=dict)
 
     def __post_init__(self):
@@ -490,6 +519,8 @@ class ExperimentSpec:
         if self.devices is not None and self.devices < 1:
             raise ValueError("ExperimentSpec: devices must be >= 1 "
                              "(None = all local devices)")
+        if self.slot_mb is not None:
+            self._validate_memory()
         if self.cluster is not None:
             from repro.cluster.spec import ClusterSpec
             if not self.cluster:
@@ -523,6 +554,41 @@ class ExperimentSpec:
                     "default device; devices must be None or 1, got "
                     f"{self.devices}")
         return self
+
+    def _validate_memory(self) -> None:
+        from repro.core.server import check_memory
+        if (isinstance(self.slot_mb, (bool, np.bool_))
+                or not isinstance(self.slot_mb, (int, np.integer))
+                or self.slot_mb < 1):
+            raise ValueError(f"ExperimentSpec: slot_mb must be a positive "
+                             f"whole number of MB, got {self.slot_mb!r}")
+        check_memory(self.policies)
+        if self.cluster is not None:
+            raise ValueError("ExperimentSpec: slot_mb is single-node "
+                             "only; drop cluster= or slot_mb")
+        if self.resilience_active():
+            raise ValueError("ExperimentSpec: slot_mb does not support "
+                             "fail_prob/timeouts/on_overflow yet")
+
+    def memory_ops(self, arrays, sources) -> Optional[np.ndarray]:
+        """The (T, F) int32 footprints of a ``slot_mb`` run over the
+        sources' materialised ``arrays``, checked against the node
+        sizes, or ``None`` without ``slot_mb``. Raises ``ValueError``
+        for a missing column or a footprint outside [`MIN_MEM_MB`, the
+        smallest lane's memory]."""
+        if self.slot_mb is None:
+            return None
+        from repro.core.server import check_memory
+        lack = [s.label for s, a in zip(sources, arrays) if MEM_COLUMN
+                not in a]
+        if lack:
+            raise ValueError(f"ExperimentSpec: slot_mb needs a "
+                             f"{MEM_COLUMN} column in every trace; "
+                             f"{lack} lack it")
+        mem = np.stack([a[MEM_COLUMN] for a in arrays]).astype(np.int32)
+        check_memory(self.policies, np.unique(mem),
+                     min(self.capacities) * self.slot_mb)
+        return mem
 
     def deadline_ops(self, n_fns: int) -> Optional[np.ndarray]:
         """Lower ``deadlines`` to the engine's (F,) float64 operand

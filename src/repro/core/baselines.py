@@ -7,19 +7,27 @@
   the function's *running-mean execution time* (shortest function first).
 * **FaasCache** [Fuerst & Sharma, ASPLOS'21] — OpenWhisk-style scheduling
   with GREEDY-DUAL keep-alive: eviction victim = idle instance with the
-  lowest priority ``clock + freq * cold_start``; the global clock is bumped
-  to the evicted priority.
+  lowest priority ``clock + freq * cold_start`` (``/ mem_mb`` when the
+  node's resource is memory: the paper's size term); the global clock is
+  bumped to the evicted priority.
 * **OpenWhisk V2** — per-function queues; a new instance is initialised
   only after the queue-head request has waited longer than a fixed
   threshold (100 ms).
 
 All four reuse the slot primitives of :class:`~repro.core.server.EdgeServer`
 so their cold-start / eviction accounting is identical to ESFF's.
+
+The three central-queue policies also run on a node whose resource is
+memory (``simulate(..., slot_mb=...)``), admitting a cold start as
+OpenWhisk's container pool does: if free memory falls short of the
+function's footprint, idle instances are evicted in the policy's victim
+order until it fits; if all of them would not free enough, nothing is
+evicted and the request waits in the central queue.
 """
 from __future__ import annotations
 
 from collections import deque
-from typing import Deque, Dict, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 from repro.core.events import EventKind
 from repro.core.policy import POLICIES, Policy
@@ -67,11 +75,47 @@ class CentralQueuePolicy(Policy):
         self.waiting += 1
 
     # -- eviction choice (overridden by FaasCache) -------------------------
+    def _victim_key(self, inst: Instance) -> Tuple:
+        return (inst.last_used, inst.inst_id)  # LRU
+
     def _victim(self) -> Optional[Instance]:
         idle = self.server.idle_instances()
         if not idle:
             return None
-        return min(idle, key=lambda i: (i.last_used, i.inst_id))  # LRU
+        return min(idle, key=self._victim_key)
+
+    def _victims(self, fn_id: int,
+                 first: Optional[Instance] = None
+                 ) -> Optional[List[Instance]]:
+        """Memory mode: the idle instances to evict, in eviction order,
+        so that a cold start of ``fn_id`` fits — ``first`` (a retargeted
+        instance) before any, then the others in victim order, no more
+        than needed. ``None`` when even all of them would not free
+        enough."""
+        mem = lambda i: self.functions[i.fn_id].mem_mb  # noqa: E731
+        need = self.functions[fn_id].mem_mb - self.server.free_mb
+        chosen = [] if first is None else [first]
+        need -= sum(mem(i) for i in chosen)
+        rest = sorted((i for i in self.server.idle_instances()
+                       if i is not first), key=self._victim_key)
+        for inst in rest:
+            if need <= 0:
+                break
+            chosen.append(inst)
+            need -= mem(inst)
+        return chosen if need <= 0 else None
+
+    def _admit(self, fn_id: int, t: float,
+               first: Optional[Instance] = None) -> None:
+        """Memory mode's cold start of ``fn_id``: evict `_victims` and
+        start, or count a wait and start nothing."""
+        victims = self._victims(fn_id, first)
+        if victims is None:
+            self.server.stats.mem_waits += 1
+            return
+        for v in victims:
+            self._note_evict(v)
+        self.server.start_cold(fn_id, t, evict=victims)
 
     def _note_evict(self, inst: Instance) -> None:
         pass
@@ -97,7 +141,9 @@ class CentralQueuePolicy(Policy):
             return
         self._push(req)
         # Scale up: no idle instance for this request.
-        if srv.has_free_slot():
+        if srv.free_mb is not None:
+            self._admit(req.fn_id, t)
+        elif srv.has_free_slot():
             srv.start_cold(req.fn_id, t)
         else:
             self._evict_and_start(req.fn_id, t)
@@ -149,7 +195,9 @@ class CentralQueuePolicy(Policy):
             if srv.instances[i].state == InstanceState.COLD
         )
         cap = len(self.fifo[head.fn_id]) if self.strict else 1
-        if warming < cap:
+        if warming < cap and srv.free_mb is not None:
+            self._admit(head.fn_id, t, first=inst)
+        elif warming < cap:
             self._note_evict(inst)
             srv.start_cold(head.fn_id, t, evict=inst)
 
@@ -171,7 +219,8 @@ class SFF(CentralQueuePolicy):
 
 @POLICIES.register("faascache")
 class FaasCache(CentralQueuePolicy):
-    """GREEDY-DUAL keep-alive eviction (size=1, cost=cold start)."""
+    """GREEDY-DUAL keep-alive eviction (cost = cold start; size = the
+    function's ``mem_mb`` when the node's resource is memory, else 1)."""
 
     name = "faascache"
 
@@ -180,19 +229,17 @@ class FaasCache(CentralQueuePolicy):
         self.clock = 0.0
 
     def _note_use(self, inst: Instance) -> None:
-        inst.priority = (
-            self.clock
-            + (inst.freq + 1) * self.functions[inst.fn_id].cold_start
-        )
+        f = self.functions[inst.fn_id]
+        value = (inst.freq + 1) * f.cold_start
+        if self.server.free_mb is not None:
+            value = value / f.mem_mb
+        inst.priority = self.clock + value
 
     def _note_evict(self, inst: Instance) -> None:
         self.clock = max(self.clock, inst.priority)
 
-    def _victim(self) -> Optional[Instance]:
-        idle = self.server.idle_instances()
-        if not idle:
-            return None
-        return min(idle, key=lambda i: (i.priority, i.inst_id))
+    def _victim_key(self, inst: Instance) -> Tuple:
+        return (inst.priority, inst.inst_id)
 
 
 @POLICIES.register("openwhisk_hol")

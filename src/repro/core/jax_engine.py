@@ -61,6 +61,15 @@ arrays carry the leading L):
           Re-arms (only ever the current queue head) keep the one-slot
           cache rearm_t (F,) f64 / rearm_rid (F,) i32. Allocated only
           when the kernel sets ``has_timers``.
+  memory: only when the node's resource is memory (``mem_mb`` given,
+          `repro.api.ExperimentSpec(slot_mb=...)`): mc (NMC,) i32 — free
+          MB, MB evicted, cold starts with two or more victims, and cold
+          starts deferred for want of memory. The slot array then holds
+          the most instances the node's memory can (``node_mb //
+          repro.core.server.MIN_MEM_MB``), and a cold start evicts the
+          shortest prefix of the idle slots in the policy's victim
+          order that makes the footprint fit (`pick_victims`,
+          `start_cold_mem`).
   ctrs:   ci (NCI,) i32 / cf (NCF,) f64 — every per-lane scalar counter
           (arrival cursor, done/event counts, stall flag, instance
           sequence, estimator globals, cold/eviction/overflow tallies
@@ -266,6 +275,12 @@ _AUTO_CHUNK: Dict[str, int] = {}
 NCI = 16
 CF_GSUM, CF_COLDT, CF_EVICTT, CF_RSUM, CF_SSUM, CF_RMAX = range(6)
 NCF = 6
+# Packed memory registers mc (NMC,) i32 of a memory-mode run: free MB, MB
+# evicted, cold starts that evicted two or more instances, cold starts
+# deferred because memory could not be freed. A run without memory
+# carries none of them.
+MC_FREE, MC_EVICT_MB, MC_MULTI, MC_WAIT = range(4)
+NMC = 4
 
 # Streaming response histogram: log-spaced, 8 bins/decade over
 # [1e-4, 1e4) seconds. Quantile reads are exact to one bin width
@@ -398,7 +413,7 @@ class EngineCtx:
                  pos_rids2, pos_off2, slabs, win_base, win_w, tix,
                  cap_mask, beta, prior, threshold, k, n, f, c, q,
                  stream=False, tl_bins=0, tl_bucket=60.0,
-                 deadlines=None):
+                 deadlines=None, mem_mb=None):
         flat = lambda a: (None if a is None          # noqa: E731
                           else a.reshape(-1))
         self._fn = flat(fn_id2)     # (T*N,) shared, flattened view
@@ -426,6 +441,9 @@ class EngineCtx:
         self._b_f1 = tix * (f + 1)     # (T, F+1) offsets
         self.t_cold = cold2        # (F,) — this lane's row, pre-gathered
         self.t_evict = evict2      # once outside the loops
+        # (F,) i32 footprints in MB when the node's resource is memory,
+        # else None (static: the slot-count path traces no memory op)
+        self.mem_mb = mem_mb
         self.cap_mask = cap_mask
         self.beta = beta
         self.prior = prior
@@ -1004,6 +1022,79 @@ def start_cold(ctx, s, slot, fn, t, evict_fn, on):
     return s
 
 
+def pick_victims(ctx, s, key, fn, first=None):
+    """Memory mode: the idle slots a cold start of ``fn`` evicts, as
+    OpenWhisk's container pool admits one. Returns ``(victims, fits,
+    slot)``: the (C,) victim mask, whether the footprint fits, and the
+    slot the new instance takes.
+
+    Victims are the shortest prefix of the idle slots, ordered by
+    ``(key, slot_seq)``, whose footprints cover the shortfall ``mem_mb[fn]
+    - free``: none when free memory suffices, and none when even every
+    idle slot would not free enough (``fits`` False). ``first`` (a slot:
+    `_serve_or_replace`'s freed instance) is evicted before any other.
+    The prefix is a dense rank, not a sort: a slot is in it when the
+    idle slots ranked before it fall short of the need. The new instance
+    takes ``first``, else the first victim's slot, else the first empty
+    one."""
+    fc = jnp.clip(fn, 0, ctx.F - 1)
+    foot = ctx.mem_mb[jnp.clip(s["slot_fn"], 0, ctx.F - 1)]
+    idle = (s["slot_state"] == IDLE) & (s["slot_fn"] >= 0) & ctx.cap_mask
+    need = ctx.mem_mb[fc] - s["mc"][MC_FREE]
+    if first is not None:
+        lead = jnp.arange(ctx.C, dtype=jnp.int32) == first
+        need = need - foot[jnp.clip(first, 0, ctx.C - 1)]
+        idle = idle & ~lead
+    seq = s["slot_seq"]
+    # before[i, j]: idle slot j ranks ahead of slot i
+    before = idle[None, :] & (
+        (key[None, :] < key[:, None])
+        | ((key[None, :] == key[:, None]) & (seq[None, :] < seq[:, None])))
+    ahead = jnp.where(before, foot[None, :], 0).sum(1)
+    fits = jnp.where(idle, foot, 0).sum() >= need
+    victims = idle & (ahead < need) & fits
+    if first is not None:
+        return victims | (lead & fits), fits, first
+    empty = (s["slot_fn"] < 0) & ctx.cap_mask
+    slot = jnp.where(victims.any(), lex_argmin(key, seq, victims),
+                     jnp.argmax(empty))
+    return victims, fits, slot
+
+
+def start_cold_mem(ctx, s, slot, fn, t, victims, on):
+    """Memory mode's `start_cold`: evict every slot of the ``victims``
+    mask and start ``fn`` in ``slot`` (see `pick_victims`). The evict
+    delay and `evictions` are summed over the victims; free memory gains
+    their footprints and loses ``fn``'s."""
+    s = dict(s)
+    fn = jnp.asarray(fn, jnp.int32)
+    fc = jnp.clip(fn, 0, ctx.F - 1)
+    vic = victims & on
+    resident = jnp.clip(s["slot_fn"], 0, ctx.F - 1)
+    ev_cost = jnp.where(vic, ctx.t_evict[resident], 0.0).sum()
+    ev_mb = jnp.where(vic, ctx.mem_mb[resident], 0).sum()
+    n_vic = vic.sum(dtype=jnp.int32)
+    # victims are idle: their slots go back to the empty state
+    s["slot_fn"] = jnp.where(vic, -1, s["slot_fn"])
+    s["slot_used"] = jnp.where(vic, 0.0, s["slot_used"])
+    s["slot_seq"] = jnp.where(vic, I32_MAX, s["slot_seq"])
+    si = _gidx(on, slot, ctx.C)
+    s["slot_fn"] = _put(s["slot_fn"], si, fn)
+    s["slot_state"] = _put(s["slot_state"], si, COLD)
+    s["slot_ready"] = _put(s["slot_ready"], si,
+                           t + ctx.t_cold[fc] + ev_cost)
+    s["slot_req"] = _put(s["slot_req"], si, -1)
+    s["slot_seq"] = _put(s["slot_seq"], si, s["ci"][CI_SEQ])
+    s["ci"] = _bumps(s["ci"], {CI_SEQ: on, CI_COLD: on, CI_EVICT: n_vic})
+    s["cf"] = _bumps(s["cf"], {CF_COLDT: jnp.where(on, ctx.t_cold[fc],
+                                                   0.0),
+                               CF_EVICTT: ev_cost})
+    s["mc"] = _bumps(s["mc"], {
+        MC_FREE: jnp.where(on, ev_mb - ctx.mem_mb[fc], 0),
+        MC_EVICT_MB: ev_mb, MC_MULTI: n_vic >= 2})
+    return s
+
+
 # ----------------------------------------------------- streaming metrics
 def hist_edges() -> np.ndarray:
     """Bin edges (HIST_BINS + 1,) of the streaming response histogram."""
@@ -1058,7 +1149,7 @@ def hist_cdf(hist):
 def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
               cap_mask, beta, prior, threshold, n_live=None,
               deadlines=None, rs_nfail=None, rs_tmo=None, rs_key=None,
-              *, kernel, n_fns, capacity, queue_cap,
+              mem_mb=None, node_mb=None, *, kernel, n_fns, capacity, queue_cap,
               stream=False, window=0, tl_bins=0, tl_bucket=60.0,
               resil=None, trace=False):
     """Lane-batched engine. Trace arrays are shared (T, ...) operands;
@@ -1100,6 +1191,15 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
     host sink once per segment through an ordered ``io_callback``.
     ``trace=False`` traces none of it — the loop lowers bitwise onto
     the unchanged program, exactly like the other optional rails.
+
+    ``mem_mb`` ((T, F) i32 footprints) and ``node_mb`` ((L,) i32 user
+    memory per lane) make the node's resource memory (`pick_victims`,
+    `start_cold_mem`; only `MEMORY_POLICIES`' kernels). ``capacity``
+    is then the slot array's length, which the runner sizes to hold the
+    largest lane's instances, and ``cap_mask`` each lane's share of it.
+    Every launch then also reports ``evict_mb``,
+    ``multi_victim_starts`` and ``mem_waits`` per lane. With ``mem_mb``
+    None none of it is traced.
     """
     L = trace_ix.shape[0]
     T_ = fn_id.shape[0]
@@ -1108,6 +1208,7 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
     nl = (jnp.full((L,), N, jnp.int32) if n_live is None
           else jnp.asarray(n_live, jnp.int32))
 
+    has_mem = mem_mb is not None
     has_resil = resil is not None
     if has_resil:
         if kernel.has_timers:
@@ -1235,6 +1336,9 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
         from repro.telemetry.rail import TR_RF, TR_RI
         s["tr_i"] = jnp.full((L, SEG, TR_RI), -1, jnp.int32)
         s["tr_f"] = jnp.zeros((L, SEG, TR_RF), jnp.float64)
+    if has_mem:
+        s["mc"] = jnp.zeros((L, NMC), jnp.int32).at[:, MC_FREE].set(
+            jnp.asarray(node_mb, jnp.int32))
     s.update(kernel.extra_state(L, C, F))
     # lane-stacked loop iterations the device runs: SEG per segment,
     # counting parked spins and finished lanes (`n_events` counts only
@@ -1252,6 +1356,8 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
     # gather would otherwise sit inside the per-event body)
     t_cold_l = t_cold[trace_ix]
     t_evict_l = t_evict[trace_ix]
+    mem_l = (jnp.asarray(mem_mb, jnp.int32)[trace_ix] if has_mem
+             else None)
     # lane-stacked arrival reads go through the flattened operand with
     # a per-lane base — a (T, N) two-dim gather only hits the fast
     # XLA:CPU path at T == 1 (see EngineCtx)
@@ -1312,7 +1418,7 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             return ei, t_ev, t_arr
 
         def lane_step(k, s, tix, cold_l, evict_l, cap_mask, beta,
-                      nl_l, ei, t_ev, t_arr):
+                      nl_l, ei, t_ev, t_arr, mem_row):
             kw = dict(fn_id2=fn_id, arrival2=arrival,
                       exec2=exec_time, cold2=cold_l,
                       evict2=evict_l, pos_rids2=pos_rids,
@@ -1321,7 +1427,8 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                       cap_mask=cap_mask, beta=beta, prior=prior,
                       threshold=threshold, k=k, n=N, f=F, c=C,
                       q=Q, stream=stream, tl_bins=tl_bins,
-                      tl_bucket=tl_bucket, deadlines=deadlines)
+                      tl_bucket=tl_bucket, deadlines=deadlines,
+                      mem_mb=mem_row)
             ctx = (ResilCtx(nfail2=rs_nfail, tmo2=rs_tmo, key2=rs_key,
                             resil=resil, **kw)
                    if has_resil else EngineCtx(**kw))
@@ -1544,7 +1651,7 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
             return s
 
         step_lanes = jax.vmap(
-            lane_step, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
+            lane_step, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
         def cond(s):
             ci = s["ci"]
@@ -1571,7 +1678,8 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
                 with jax.named_scope("repro.pick"):
                     ei, t_ev, t_arr = pick_events(s)
                 return step_lanes(k, s, trace_ix, t_cold_l, t_evict_l,
-                                  cap_mask, beta, nl, ei, t_ev, t_arr)
+                                  cap_mask, beta, nl, ei, t_ev, t_arr,
+                                  mem_l)
 
             s = lax.fori_loop(0, SEG, step, s)
             if not stream:
@@ -1607,6 +1715,11 @@ def _simulate(fn_id, arrival, exec_time, t_cold, t_evict, trace_ix,
         out["tl_exec_sum"] = final["tl_exec"]
     if deadlines is not None:
         out["deadline_miss"] = final["dl_miss"]
+    if has_mem:
+        mc = final["mc"]
+        out["evict_mb"] = mc[:, MC_EVICT_MB]
+        out["multi_victim_starts"] = mc[:, MC_MULTI]
+        out["mem_waits"] = mc[:, MC_WAIT]
     if has_resil:
         out["failed"] = ci[:, CI_FAILED]
         out["timed_out"] = ci[:, CI_TMO]
@@ -1626,7 +1739,8 @@ def simulate_policy_jax(fn_id, arrival, exec_time, t_cold, t_evict, *,
                         prior: float = 0.1, threshold: float = 0.1,
                         cap_mask=None, stream: bool = False,
                         window: int = 0, tl_bins: int = 0,
-                        tl_bucket: float = 60.0
+                        tl_bucket: float = 60.0, mem_mb=None,
+                        slot_mb: Optional[int] = None
                         ) -> Dict[str, jnp.ndarray]:
     """Run ``policy`` over a (sorted-by-arrival) request stream.
 
@@ -1640,22 +1754,32 @@ def simulate_policy_jax(fn_id, arrival, exec_time, t_cold, t_evict, *,
     evictions, overflow, stalled) plus the streaming metric
     accumulators (resp_sum / slow_sum / max_response / resp_hist);
     with the default ``stream=False`` also per-request
-    start/completion.
+    start/completion. ``slot_mb`` with the (F,) footprints ``mem_mb``
+    makes the node ``capacity * slot_mb`` MB of memory (see `_simulate`).
     """
     from repro.core.jax_policies import KERNELS  # deferred: cycle-free
+    from repro.core.server import MIN_MEM_MB, check_memory
     kernel = KERNELS[policy]
     if beta is None:
         beta = kernel.default_beta
+    share = lambda x: jnp.expand_dims(jnp.asarray(x), 0)  # noqa: E731
+    mem_op = node_op = None
+    if slot_mb is not None:
+        check_memory([policy], [None] if mem_mb is None
+                     else np.unique(mem_mb), capacity * slot_mb)
+        node_op = jnp.full((1,), capacity * slot_mb, jnp.int32)
+        mem_op = share(jnp.asarray(mem_mb, jnp.int32))
+        capacity = capacity * slot_mb // MIN_MEM_MB
     if cap_mask is None:
         cap_mask = jnp.ones((capacity,), bool)
-    share = lambda x: jnp.expand_dims(jnp.asarray(x), 0)  # noqa: E731
     out = _simulate(share(fn_id), share(arrival), share(exec_time),
                     share(t_cold), share(t_evict),
                     jnp.zeros((1,), jnp.int32),
                     jnp.expand_dims(jnp.asarray(cap_mask), 0),
                     jnp.asarray(beta, jnp.float64).reshape((1,)),
                     jnp.float64(prior), jnp.float64(threshold),
-                    kernel=kernel, n_fns=n_fns, capacity=capacity,
+                    mem_mb=mem_op, node_mb=node_op, kernel=kernel,
+                    n_fns=n_fns, capacity=capacity,
                     queue_cap=queue_cap, stream=stream, window=window,
                     tl_bins=tl_bins, tl_bucket=tl_bucket)
     out.pop("loop_steps")
@@ -1666,7 +1790,8 @@ def simulate_policy_from_trace(trace: Trace, policy: str, capacity: int,
                                *, beta=None, queue_cap: int = 1024,
                                prior: float = 0.1,
                                threshold: float = 0.1,
-                               window: int = 0
+                               window: int = 0,
+                               slot_mb: Optional[int] = None
                                ) -> Dict[str, np.ndarray]:
     """Trace-object convenience wrapper mirroring ``simulate()``
     (exact per-request mode)."""
@@ -1676,7 +1801,8 @@ def simulate_policy_from_trace(trace: Trace, policy: str, capacity: int,
         jnp.asarray(a["exec_time"]), jnp.asarray(a["cold_start"]),
         jnp.asarray(a["evict"]), policy=policy,
         n_fns=trace.n_functions, capacity=capacity, queue_cap=queue_cap,
-        beta=beta, prior=prior, threshold=threshold, window=window)
+        beta=beta, prior=prior, threshold=threshold, window=window,
+        mem_mb=a.get("mem_mb"), slot_mb=slot_mb)
     out = {k: np.asarray(v) for k, v in out.items()}
     out["response"] = out["completion"] - a["arrival"]
     out["mean_response"] = float(out["response"].mean())
@@ -1691,7 +1817,8 @@ def simulate_policy_from_trace(trace: Trace, policy: str, capacity: int,
                    compiler_options=LOOP_COMPILER_OPTIONS)
 def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                    threshold, n_live=None, deadlines=None,
-                   rs_nfail=None, rs_tmo=None, rs_key=None, *, kernel,
+                   rs_nfail=None, rs_tmo=None, rs_key=None, mem_mb=None,
+                   node_mb=None, *, kernel,
                    n_fns, capacity, queue_cap, stream=True, window=0,
                    tl_bins=0, tl_bucket=60.0, keep_responses=False,
                    resil=None, trace=False):
@@ -1704,12 +1831,14 @@ def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
     `repro.api.ExperimentSpec(keep_per_request=True)` exposes.
     ``n_live`` ((L,) i32) marks lanes as ragged prefixes of their
     padded trace rows (see `_simulate`); means/quantiles then reduce
-    over each lane's live prefix only."""
+    over each lane's live prefix only. ``mem_mb`` / ``node_mb`` make
+    the node's resource memory (see `_simulate`), adding ``evict_mb``,
+    ``multi_victim_starts`` and ``mem_waits`` per lane."""
     if keep_responses and stream:
         raise ValueError("keep_responses requires stream=False")
     out = _simulate(fn, arr, ex, cold, ev, tix, masks, betas, prior,
                     threshold, n_live, deadlines, rs_nfail, rs_tmo,
-                    rs_key, kernel=kernel,
+                    rs_key, mem_mb, node_mb, kernel=kernel,
                     n_fns=n_fns, capacity=capacity, queue_cap=queue_cap,
                     stream=stream, window=window, tl_bins=tl_bins,
                     tl_bucket=tl_bucket, resil=resil, trace=trace)
@@ -1763,6 +1892,9 @@ def _sweep_metrics(fn, arr, ex, cold, ev, tix, masks, betas, prior,
         res["tl_exec_sum"] = out["tl_exec_sum"]
     if deadlines is not None:
         res["deadline_miss"] = out["deadline_miss"]
+    if mem_mb is not None:
+        for key in ("evict_mb", "multi_victim_starts", "mem_waits"):
+            res[key] = out[key]
     if resil is not None:
         for key in ("failed", "timed_out", "retried", "shed",
                     "failed_exhausted"):

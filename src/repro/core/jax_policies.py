@@ -18,10 +18,16 @@ its event-driven counterpart (tests/test_jax_engine.py):
 * **faascache** — OpenWhisk scheduling with GREEDY-DUAL keep-alive
   [Fuerst & Sharma, ASPLOS'21]: per-slot ``slot_freq``/``slot_prio``
   state plus a global clock; eviction victim = lowest
-  ``clock + freq * cold_start`` priority, clock bumped to the evicted
-  priority.
+  ``clock + freq * cold_start`` priority (``/ mem_mb`` on a node of
+  memory), clock bumped to the evicted priority.
 * **openwhisk_v2** — per-function queues; a queue head must wait
   ``threshold`` (100 ms) before scale-up, enforced with engine timers.
+
+The three central-queue kernels also run on a node whose resource is
+memory (``ctx.mem_mb`` set): a cold start evicts the shortest prefix of
+the idle slots in the victim order that makes its footprint fit, or
+none, and waits when even all of them would not (`_admit`, under the
+``repro.admit`` named scope).
 
 Hooks follow the engine's guarded-write convention: they execute every
 loop iteration, compute with possibly-garbage values when their ``on``
@@ -46,15 +52,17 @@ kernel must never write result state directly.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from repro.core.jax_engine import (BIG, COLD, IDLE, PolicyKernel,
+from repro.core.jax_engine import (BIG, COLD, IDLE, MC_WAIT, PolicyKernel,
                                    _bump, _gidx, _put, arm_timer,
                                    cold_counts, dispatch, est_means,
                                    fn_count, k_counts, lex_argmin,
-                                   pick_idle_own, q_consume_direct,
-                                   q_head, q_pop, q_push, rearm_timer,
-                                   start_cold)
+                                   pick_idle_own, pick_victims,
+                                   q_consume_direct, q_head, q_pop,
+                                   q_push, rearm_timer, start_cold,
+                                   start_cold_mem)
 
 
 class ESFFKernel(PolicyKernel):
@@ -167,8 +175,31 @@ class CentralQueueKernel(PolicyKernel):
     def _note_evict(self, ctx, s, victim, on):
         return s
 
+    def _note_evicts(self, ctx, s, victims, on):
+        """`_note_evict` for a (C,) mask of victims (memory mode)."""
+        return s
+
+    def _fresh(self, ctx, s, slot, on):
+        """Reset the kernel's own state of a newly started instance."""
+        return s
+
     def _start_cold(self, ctx, s, slot, fn, t, evict_fn, on):
-        return start_cold(ctx, s, slot, fn, t, evict_fn, on)
+        s = start_cold(ctx, s, slot, fn, t, evict_fn, on)
+        return self._fresh(ctx, s, slot, on)
+
+    def _admit(self, ctx, s, fn, t, on, first=None):
+        """Memory mode's cold start of ``fn``: evict the victims
+        `pick_victims` chooses in this kernel's order (``first`` before
+        any) and start, or count a wait and start nothing."""
+        with jax.named_scope("repro.admit"):
+            victims, fits, slot = pick_victims(
+                ctx, s, self._victim_key(ctx, s), fn, first)
+            go = on & fits
+            s = self._note_evicts(ctx, s, victims, go)
+            s = start_cold_mem(ctx, s, slot, fn, t, victims, go)
+            s = self._fresh(ctx, s, slot, go)
+            s["mc"] = _bump(s["mc"], MC_WAIT, on & ~fits)
+        return s
 
     def _head_fn(self, ctx, s):
         """Central-queue head: (exists, fn). Requests are globally
@@ -185,7 +216,10 @@ class CentralQueueKernel(PolicyKernel):
     def _scale_up(self, ctx, s, j, t, on):
         """No idle instance for an arrival of ``j``: claim a free slot,
         else evict the keep-alive victim (LRU here; GREEDY-DUAL in
-        FaasCache — ties: earliest-created)."""
+        FaasCache — ties: earliest-created). On a node of memory the
+        cold start may evict several idle instances, or wait."""
+        if ctx.mem_mb is not None:
+            return self._admit(ctx, s, j, t, on)
         empty = (s["slot_fn"] < 0) & ctx.cap_mask
         s = self._start_cold(ctx, s, jnp.argmax(empty), j, t, -1,
                              on & empty.any())
@@ -216,7 +250,9 @@ class CentralQueueKernel(PolicyKernel):
     def _serve_or_replace(self, ctx, s, slot, t, on):
         """Central-queue discipline for a freed idle slot: drain its own
         function's earliest request (warm reuse), else retarget to the
-        queue-head function — at most one warming replica at a time."""
+        queue-head function — at most one warming replica at a time.
+        On a node of memory the freed instance is the first victim of
+        the retarget, and more follow only as its footprint needs."""
         j = s["slot_fn"][slot]
         own = on & (s["q_len"][jnp.clip(j, 0, ctx.F - 1)] > 0)
         s, rid = q_pop(ctx, s, j, own)
@@ -226,6 +262,8 @@ class CentralQueueKernel(PolicyKernel):
         warming = ((s["slot_fn"] == f) & (s["slot_state"] == COLD)
                    & ctx.cap_mask).any()
         retarget = on & ~own & exists & ~warming
+        if ctx.mem_mb is not None:
+            return self._admit(ctx, s, f, t, retarget, first=slot)
         s = self._note_evict(ctx, s, slot, retarget)
         return self._start_cold(ctx, s, slot, f, t, j, retarget)
 
@@ -242,10 +280,12 @@ class FaasCacheKernel(CentralQueueKernel):
     `repro.core.baselines.FaasCache`.
 
     Per-slot state: ``slot_freq`` (use count of the resident instance)
-    and ``slot_prio`` (= clock + freq * cold_start, recomputed at every
+    and ``slot_prio`` (= clock + freq * cold_start, divided by the
+    footprint ``mem_mb`` on a node of memory; recomputed at every
     dispatch with the pre-increment freq + 1, exactly the Python
     ``_note_use``/``dispatch`` order); ``gd_clock`` is the global clock,
-    bumped to the victim's priority on every eviction. A fresh instance
+    bumped to the victim's priority on every eviction (to the largest
+    victim priority when a cold start evicts several). A fresh instance
     keeps priority 0.0 until its first dispatch (the Python
     ``Instance`` default), which is what ages never-used instances out
     first."""
@@ -263,8 +303,10 @@ class FaasCacheKernel(CentralQueueKernel):
     def _dispatch(self, ctx, s, slot, rid, t, on):
         sc = jnp.clip(slot, 0, ctx.C - 1)
         fn = jnp.clip(s["slot_fn"][sc], 0, ctx.F - 1)
-        prio = (s["gd_clock"]
-                + (s["slot_freq"][sc] + 1.0) * ctx.t_cold[fn])
+        value = (s["slot_freq"][sc] + 1.0) * ctx.t_cold[fn]
+        if ctx.mem_mb is not None:
+            value = value / ctx.mem_mb[fn]
+        prio = s["gd_clock"] + value
         si = _gidx(on, slot, ctx.C)
         s = dict(s)
         s["slot_freq"] = _bump(s["slot_freq"], si, 1)
@@ -281,9 +323,15 @@ class FaasCacheKernel(CentralQueueKernel):
             s["gd_clock"], jnp.where(on, prio, -BIG))
         return s
 
-    def _start_cold(self, ctx, s, slot, fn, t, evict_fn, on):
-        s = start_cold(ctx, s, slot, fn, t, evict_fn, on)
+    def _note_evicts(self, ctx, s, victims, on):
+        prio = jnp.where(victims & on, s["slot_prio"], -BIG).max()
+        s = dict(s)
+        s["gd_clock"] = jnp.maximum(s["gd_clock"], prio)
+        return s
+
+    def _fresh(self, ctx, s, slot, on):
         si = _gidx(on, slot, ctx.C)
+        s = dict(s)
         s["slot_freq"] = _put(s["slot_freq"], si, 0)
         s["slot_prio"] = _put(s["slot_prio"], si, 0.0)
         return s

@@ -31,6 +31,9 @@ class FunctionProfile:
     # by the oracle estimator mode, never by the online scheduler.
     true_mean_exec: float = 0.0
     name: str = ""
+    # Memory footprint in MB (an integer); read only when the node's
+    # resource is memory (``slot_mb``), see `repro.core.server`.
+    mem_mb: Optional[int] = None
 
     def __post_init__(self) -> None:
         if not self.name:
@@ -108,7 +111,8 @@ class Trace:
         """Columnar view (used by the vectorized JAX simulator and npz io).
 
         Memoized: the exported columns (ids, arrivals, exec/cold/evict
-        times) are immutable for a Trace's lifetime — the simulator
+        times, and ``mem_mb`` when every function has a footprint) are
+        immutable for a Trace's lifetime — the simulator
         only ever mutates per-request ``start``/``completion``, which
         are not part of the view — and re-walking 10^4+ Request objects
         per ``sweep`` call is pure-Python overhead the vectorised
@@ -126,14 +130,19 @@ class Trace:
                              np.float64)
             self._arrays = dict(fn_id=fn, arrival=arr, exec_time=ex,
                                 cold_start=cold, evict=evict)
+            if all(f.mem_mb is not None for f in self.functions):
+                self._arrays["mem_mb"] = np.array(
+                    [f.mem_mb for f in self.functions], np.int32)
             for v in self._arrays.values():
                 v.setflags(write=False)   # shared across calls
         return dict(self._arrays)
 
     @staticmethod
     def from_arrays(a: dict, meta: Optional[dict] = None) -> "Trace":
+        mem = a.get("mem_mb")
         funcs = [
-            FunctionProfile(j, float(c), float(v))
+            FunctionProfile(j, float(c), float(v),
+                            mem_mb=None if mem is None else int(mem[j]))
             for j, (c, v) in enumerate(zip(a["cold_start"], a["evict"]))
         ]
         reqs = [

@@ -5,16 +5,53 @@ eviction+cold-start) transition begins until it is evicted. Replacing an
 idle instance of f_{j'} by f_j therefore keeps the slot count at C and
 costs ``t_{j'}^v + t_j^l`` before the new instance becomes ready — exactly
 the paper's cost model.
+
+With ``slot_mb`` set the node's resource is memory instead, as in
+OpenWhisk's invoker container pool: a node of C slots holds
+``C * slot_mb`` MB of user memory, an instance holds its function's
+``mem_mb`` from the start of its cold start until it is evicted, and a
+cold start may evict several idle instances (or none) to fit. The policy
+chooses them (`repro.core.baselines.CentralQueuePolicy`).
 """
 from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass, field
 from enum import IntEnum
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Sequence, Set, Union
 
 from repro.core.events import EventKind, EventQueue
 from repro.core.request import FunctionProfile, Request
+
+# The smallest footprint an instance may have, in MB: OpenWhisk's
+# minimum action memory. It bounds how many instances a node of memory
+# holds, so it sizes the slot array of a memory-mode run.
+MIN_MEM_MB = 128
+# The policies that run on a node of memory: the central-queue policies,
+# whose cold start admits by memory (`repro.core.baselines`,
+# `repro.core.jax_policies.CentralQueueKernel`).
+MEMORY_POLICIES = ("openwhisk", "sff", "faascache")
+
+
+def check_memory(policies: Sequence[str], mem_mb: Sequence = (),
+                 node_mb: int = 0) -> None:
+    """Raise ``ValueError`` unless every one of ``policies`` runs on a
+    node of memory and every footprint in ``mem_mb`` (``None`` for a
+    function without one) lies in [`MIN_MEM_MB`, ``node_mb``]. The one
+    check of ``ExperimentSpec(slot_mb=)``, ``simulate(slot_mb=)`` and
+    ``simulate_policy_jax(slot_mb=)``."""
+    plain = [p for p in policies if p not in MEMORY_POLICIES]
+    if plain:
+        raise ValueError(f"policies {plain} have no memory semantics; "
+                         f"slot_mb runs only {', '.join(MEMORY_POLICIES)}")
+    mem = list(mem_mb)
+    if None in mem:
+        raise ValueError("slot_mb needs a mem_mb footprint for every "
+                         "function")
+    bad = sorted({int(m) for m in mem if not MIN_MEM_MB <= m <= node_mb})
+    if bad:
+        raise ValueError(f"mem_mb must lie in [{MIN_MEM_MB}, {node_mb}] "
+                         f"MB, the smallest node's memory; got {bad[:5]}")
 
 
 class InstanceState(IntEnum):
@@ -76,13 +113,18 @@ class ServerStats:
     evictions: int = 0
     evict_time: float = 0.0
     busy_time: float = 0.0
+    # memory mode only: MB evicted, cold starts that evicted two or more
+    # instances, and cold starts deferred for want of memory
+    evict_mb: int = 0
+    multi_victim_starts: int = 0
+    mem_waits: int = 0
 
 
 class EdgeServer:
     """Slot/instance bookkeeping shared by every scheduling policy."""
 
     def __init__(self, functions: List[FunctionProfile], capacity: int,
-                 events: EventQueue):
+                 events: EventQueue, slot_mb: Optional[int] = None):
         self.functions = functions
         self.capacity = capacity
         self.events = events
@@ -90,6 +132,9 @@ class EdgeServer:
         self.by_fn: Dict[int, Set[int]] = {f.fn_id: set() for f in functions}
         self.stats = ServerStats()
         self._ids = itertools.count()
+        # free user memory in MB; None when the resource is slots
+        self.free_mb: Optional[int] = (None if slot_mb is None
+                                       else capacity * slot_mb)
 
     # ------------------------------------------------------------ queries
     def total_instances(self) -> int:
@@ -133,18 +178,30 @@ class EdgeServer:
         self.events.push(req.completion, EventKind.EXEC_DONE, inst)
 
     def start_cold(self, fn_id: int, t: float,
-                   evict: Optional[Instance] = None) -> Instance:
+                   evict: Union[Instance, Sequence[Instance], None] = None
+                   ) -> Instance:
         """Begin initialising a new instance of f_j, optionally by evicting
-        an *idle* instance first (cost t_v of the evicted function)."""
+        *idle* instances first (cost t_v of each evicted function, added
+        in the order given). ``evict`` is one instance, or in memory mode
+        the list of victims, which may be empty."""
         delay = self.functions[fn_id].cold_start
-        if evict is not None:
-            assert evict.state == InstanceState.IDLE, evict
-            delay += self.functions[evict.fn_id].evict
+        victims = ([] if evict is None else [evict]
+                   if isinstance(evict, Instance) else list(evict))
+        for v in victims:
+            assert v.state == InstanceState.IDLE, v
+            delay += self.functions[v.fn_id].evict
             self.stats.evictions += 1
-            self.stats.evict_time += self.functions[evict.fn_id].evict
-            self._remove(evict)
-        if len(self.instances) >= self.capacity:
-            raise RuntimeError("start_cold would exceed capacity")
+            self.stats.evict_time += self.functions[v.fn_id].evict
+            self._remove(v)
+        if self.free_mb is None:
+            if len(self.instances) >= self.capacity:
+                raise RuntimeError("start_cold would exceed capacity")
+        else:
+            mem = self.functions[fn_id].mem_mb
+            if mem > self.free_mb:
+                raise RuntimeError("start_cold would exceed node memory")
+            self.free_mb -= mem
+            self.stats.multi_victim_starts += len(victims) >= 2
         inst = Instance(next(self._ids), fn_id, InstanceState.COLD,
                         ready_at=t + delay)
         self.instances[inst.inst_id] = inst
@@ -161,3 +218,7 @@ class EdgeServer:
     def _remove(self, inst: Instance) -> None:
         del self.instances[inst.inst_id]
         self.by_fn[inst.fn_id].discard(inst.inst_id)
+        if self.free_mb is not None:
+            mem = self.functions[inst.fn_id].mem_mb
+            self.free_mb += mem
+            self.stats.evict_mb += mem

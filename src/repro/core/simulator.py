@@ -12,22 +12,30 @@ from repro.core.events import EventKind, EventQueue
 from repro.core.metrics import SimResult, collect
 from repro.core.policy import POLICIES, Policy
 from repro.core.request import Trace
-from repro.core.server import EdgeServer, ExecTimeEstimator
+from repro.core.server import EdgeServer, ExecTimeEstimator, check_memory
 
 
 def simulate(trace: Trace, policy: Union[str, Policy], capacity: int,
              *, oracle_exec: bool = False, exec_prior: float = 0.1,
-             max_events: Optional[int] = None) -> SimResult:
+             max_events: Optional[int] = None,
+             slot_mb: Optional[int] = None) -> SimResult:
     """Run ``policy`` over ``trace`` on a C-slot edge server.
 
     oracle_exec=True gives the scheduler the true per-function mean
     execution times (used for validation); the default estimates them
     online from completions, as the paper's ESFF does.
+
+    ``slot_mb`` makes the node's resource memory: ``capacity * slot_mb``
+    MB, shared by instances that each hold their function's ``mem_mb``
+    (`repro.core.server`). Only `MEMORY_POLICIES` support it.
     """
     if isinstance(policy, str):
         policy = POLICIES[policy]()
+    if slot_mb is not None:
+        check_memory([policy.name], [f.mem_mb for f in trace.functions],
+                     capacity * slot_mb)
     events = EventQueue()
-    server = EdgeServer(trace.functions, capacity, events)
+    server = EdgeServer(trace.functions, capacity, events, slot_mb=slot_mb)
     oracle = ([f.true_mean_exec for f in trace.functions]
               if oracle_exec else None)
     est = ExecTimeEstimator(trace.n_functions, prior=exec_prior,

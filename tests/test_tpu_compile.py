@@ -117,8 +117,12 @@ def _loop_scatters(hlo):
     dict(L=7, stream=True, resil=RESIL_SHED_OLDEST),
     # ESFF-H adds the cold-slot counts to the same step
     dict(L=7, stream=True, policy="esff_h", no_loop_scatter=True),
+    # a node of memory: 32 slots of 256 MB hold up to 64 instances of
+    # 128 MB, and the victim prefix is a dense rank, not a scatter
+    dict(L=7, stream=True, policy="faascache", slots=64, memory=True,
+         no_loop_scatter=True),
 ], ids=["stream", "exact_keep_responses", "resil_shed_oldest",
-        "esff_h_stream"])
+        "esff_h_stream", "faascache_mem"])
 def test_single_node_loop_compiles(shape, case):
     from repro.core.jax_engine import _sweep_metrics
     from repro.core.jax_policies import KERNELS
@@ -126,10 +130,14 @@ def test_single_node_loop_compiles(shape, case):
     L = case.pop("L")
     policy = case.pop("policy", "esff")
     no_loop_scatter = case.pop("no_loop_scatter", False)
+    C = case.pop("slots", 32)
     extra = _resil_ops(shape) if "resil" in case else {}
+    if case.pop("memory", False):
+        extra.update(mem_mb=shape((1, F), jnp.int32),
+                     node_mb=shape((L,), jnp.int32))
     compiled = _sweep_metrics.lower(
-        *_trace_args(shape, L, (32,)), **extra, kernel=KERNELS[policy],
-        n_fns=F, capacity=32, queue_cap=N, **case).compile()
+        *_trace_args(shape, L, (C,)), **extra, kernel=KERNELS[policy],
+        n_fns=F, capacity=C, queue_cap=N, **case).compile()
     hlo = compiled.as_text()
     assert hlo
     if no_loop_scatter:
